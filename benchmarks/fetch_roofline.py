@@ -13,8 +13,11 @@ Arms (hypothesis -> expected collective-term delta):
   C. stratified + int8 block-quantized payload + scales (wire ~/2 again;
      dequant runs at HBM bw on device — the paper's Fig-10 trade on ICI)
 
-Runs under a subprocess with 512 fake devices; parses the compiled HLO's
-collective payloads (same methodology as the dry-run roofline).
+A CPU compile, not a chip run: the child runs with ``JAX_PLATFORMS=cpu``
+and 512 fake host devices, parses the compiled HLO's collective payloads
+(same methodology as the dry-run roofline), and turns wire bytes into a
+collective term with the published TPU v5e link rate. A failed child
+raises.
 """
 from __future__ import annotations
 
@@ -33,8 +36,10 @@ import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core import DeviceStore, DeviceStoreConfig
 from repro.launch.mesh import make_production_mesh
-from repro.utils.roofline import parse_collectives, LINK_BW
+from repro.utils.roofline import parse_collectives, peaks
 
+assert jax.devices()[0].platform == "cpu"
+LINK_BW = peaks("TPU v5 lite").link_bw
 mesh = make_production_mesh(multi_pod=False)
 G = 256
 SEQ = 4096
@@ -52,7 +57,8 @@ def lower_arm(name, sample_bytes, cf):
         compiled = lowered.compile()
     stats = parse_collectives(compiled.as_text())
     term_us = stats.wire_bytes / LINK_BW * 1e6
-    print(f"fetch_arm,{name},cf={cf},sample_bytes={sample_bytes},"
+    print(f"fetch_arm,{name},cpu_compile,target=TPU v5 lite,"
+          f"cf={cf},sample_bytes={sample_bytes},"
           f"wire_bytes={int(stats.wire_bytes)},coll_term_us={term_us:.1f},"
           f"by_kind={stats.bytes_by_kind}")
     return stats.wire_bytes
@@ -69,12 +75,15 @@ print(f"fetch_arm,summary,B_vs_A={a/b:.2f}x,C_vs_A={a/c:.2f}x")
 
 def main() -> List[str]:
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(_CODE)],
                          capture_output=True, text=True, env=env,
                          timeout=580)
     if out.returncode != 0:
-        return [f"fetch_arm,ERROR,{out.stderr.strip()[-300:]}"]
+        raise RuntimeError(f"fetch_roofline child failed "
+                           f"(rc={out.returncode}):\n"
+                           f"{out.stderr.strip()[-2000:]}")
     return [l for l in out.stdout.splitlines() if l.startswith("fetch_arm,")]
 
 

@@ -61,6 +61,7 @@ for _p in (str(_ROOT), str(_ROOT / "src")):
 
 from repro.fanstore.metrics import (JsonlSink, MetricsCollector, Ref,  # noqa: E402
                                     SloGuard, check_slos)
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 # Every BENCH_io.json perf-trajectory guard, as data. Paths are dotted
 # with `*` wildcards; a Ref threshold compares against another path (its
@@ -360,6 +361,7 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="tiny io-json variant for the CI fast lane")
     args = ap.parse_args()
+    enable_compile_cache()
 
     sections = {
         "fig3": lambda: __import__("benchmarks.io_single_node",

@@ -96,6 +96,16 @@ class ModelConfig:
         """A reduced copy for smoke tests (same family/topology, tiny dims)."""
         return replace(self, **overrides)
 
+    def with_depth(self, num_layers: int) -> "ModelConfig":
+        """Same widths, ``num_layers`` deep. Global-attention layers stay at
+        the first, evenly spaced and last positions, as many as fit."""
+        if not self.global_layers:
+            return replace(self, num_layers=num_layers)
+        n, last = len(self.global_layers), num_layers - 1
+        pos = [0] if n == 1 else [i * last // (n - 1) for i in range(n)]
+        return replace(self, num_layers=num_layers,
+                       global_layers=tuple(sorted(set(pos))))
+
 
 @dataclass(frozen=True)
 class ShapeConfig:

@@ -38,8 +38,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.utils.compat import shard_map
-
 
 def required_capacity(local_batch: int, num_shards: int,
                       capacity_factor: float) -> int:
@@ -109,9 +107,9 @@ def make_fetch_fn(mesh: Mesh, *, num_samples: int, sample_bytes: int,
         overflow = (jnp.sum(mine, axis=1) > cap).any()
         return out, overflow[None]
 
-    shmap = shard_map(local_fn, mesh=mesh,
-                      in_specs=(store_spec, idx_spec),
-                      out_specs=out_spec, check_vma=False)
+    shmap = jax.shard_map(local_fn, mesh=mesh,
+                          in_specs=(store_spec, idx_spec),
+                          out_specs=out_spec, check_vma=False)
 
     def fetch(store: jax.Array, idx: jax.Array):
         return shmap(store, idx)

@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.utils.compat import shard_map
-
 
 def split_stages(params: Any, num_stages: int) -> Any:
     """(L, ...) leaves -> (num_stages, L/num_stages, ...) leaves."""
@@ -74,7 +72,7 @@ def pipeline_apply(fn: Callable[[Any, jnp.ndarray], jnp.ndarray],
         return lax.psum(out * keep, stage_axis)
 
     stage_spec = jax.tree.map(lambda _: P(stage_axis), staged_params)
-    result = shard_map(local_fn, mesh=mesh,
-                       in_specs=(stage_spec, P()), out_specs=P(),
-                       check_vma=False)(staged_params, xs)
+    result = jax.shard_map(local_fn, mesh=mesh,
+                           in_specs=(stage_spec, P()), out_specs=P(),
+                           check_vma=False)(staged_params, xs)
     return result.reshape((batch,) + x.shape[1:])

@@ -8,8 +8,11 @@ structural win over the lax reference (repro.models.layers.
 flash_attention_lax) that must visit every block.
 
 GQA is handled in the index map: query head h reads kv head h // group.
-Sliding-window masking composes with causal in-block masks. Head dim goes
-to the MXU lane dim — multiples of 128 are the fast path.
+Sliding-window masking composes with causal in-block masks. The wrapper
+moves heads in front of time, (B, H, T, dh), so every block's last two dims
+are (block, dh): a TPU block's second-to-last dim must be a multiple of 8
+(or whole), and a single head there is neither. Head dim goes to the MXU
+lane dim — multiples of 128 are the fast path.
 """
 from __future__ import annotations
 
@@ -49,9 +52,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)          # (bq, dh)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)          # (bk, dh)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)          # (bk, dv)
+        q = q_ref[...].astype(jnp.float32)                 # (bq, dh)
+        k = k_ref[...].astype(jnp.float32)                 # (bk, dh)
+        v = v_ref[...].astype(jnp.float32)                 # (bk, dv)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
@@ -77,7 +80,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     @pl.when(ki == nk - 1)
     def _emit():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_scr[...] / l).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -101,23 +104,27 @@ def flash_attention(q, k, v, *, causal: bool = True,
     grid = (b, h, t // bq, t // bk)
     kernel = functools.partial(_flash_kernel, scale=scale, causal=causal,
                                window=window, bq=bq, bk=bk)
-    return pl.pallas_call(
+    sq = pl.Squeezed()
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bq, 1, dh), lambda b_, h_, qi, ki: (b_, qi, h_, 0)),
-            pl.BlockSpec((1, bk, 1, dh),
-                         lambda b_, h_, qi, ki: (b_, ki, h_ // g, 0)),
-            pl.BlockSpec((1, bk, 1, dv),
-                         lambda b_, h_, qi, ki: (b_, ki, h_ // g, 0)),
+            pl.BlockSpec((sq, sq, bq, dh),
+                         lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
+            pl.BlockSpec((sq, sq, bk, dh),
+                         lambda b_, h_, qi, ki: (b_, h_ // g, ki, 0)),
+            pl.BlockSpec((sq, sq, bk, dv),
+                         lambda b_, h_, qi, ki: (b_, h_ // g, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, dv),
-                               lambda b_, h_, qi, ki: (b_, qi, h_, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, t, h, dv), q.dtype),
+        out_specs=pl.BlockSpec((sq, sq, bq, dv),
+                               lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, h, t, dv), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, dv), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v)
+        name="flash_attention",
+    )(q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2))
+    return out.swapaxes(1, 2)

@@ -1,8 +1,11 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+if __name__ == "__main__":
+    # A CPU compile tool: 512 fake host devices stand in for the pods, and
+    # no accelerator is opened. These MUST be set before jax initializes
+    # its backend (it locks the device count on first use).
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
-# NOTE: the two lines above MUST run before any jax-touching import — jax
-# locks the device count on first init (see the multi-pod dry-run contract).
 _DOC = """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this lowers the right step function (train_step for train_*,
@@ -40,6 +43,11 @@ from repro.serve.serve_step import make_decode_step, make_prefill_step
 from repro.train.optimizer import OptimizerConfig, adamw_init
 from repro.train.train_step import TrainState, make_train_step
 from repro.utils import roofline
+
+# The chip whose published peaks turn this CPU compile's FLOP, byte and
+# wire counts into roofline terms. The compile itself runs on fake host
+# devices; no term here is a measured time.
+TARGET_KIND = "TPU v5 lite"
 
 
 def _sds(shape, dtype, sharding=None):
@@ -98,13 +106,7 @@ def depth_variant(cfg: ModelConfig, L: int) -> ModelConfig:
     per-scanned-layer cost. The full-depth compile still provides
     memory_analysis (fit) and the collective schedule.
     """
-    overrides: Dict[str, Any] = {"num_layers": L, "unroll": True}
-    if cfg.global_layers:
-        n = len(cfg.global_layers)
-        pos = [0] + [((i * (L - 1)) // (n - 1)) for i in range(1, n - 1)] + [L - 1] \
-            if n > 1 else [0]
-        overrides["global_layers"] = tuple(sorted(set(pos)))
-    return cfg.scaled(**overrides)
+    return cfg.with_depth(L).scaled(unroll=True)
 
 
 def variant_depths(cfg: ModelConfig) -> Tuple[int, int]:
@@ -199,8 +201,6 @@ def _mem_dict(compiled) -> Tuple[Dict, Optional[int]]:
 
 def _cell_costs(compiled) -> Dict[str, float]:
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):      # jax<=0.4 returns [dict]
-        cost = cost[0] if cost else {}
     stats = roofline.parse_collectives(compiled.as_text())
     return {"flops": float(cost.get("flops", 0.0)),
             "bytes": float(cost.get("bytes accessed", 0.0)),
@@ -276,15 +276,16 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
             cost = {"flops": raw["flops"], "bytes accessed": raw["bytes"]}
             wire = raw["wire"]
             coll = raw["coll_by_kind"]
+        chip = roofline.peaks(TARGET_KIND)
         rep = roofline.RooflineReport(
             arch=arch, shape=shape_name, mesh=info["mesh"],
-            chips=info["chips"],
+            chips=info["chips"], device_kind=TARGET_KIND,
             flops_per_device=cost["flops"],
             bytes_per_device=cost["bytes accessed"],
             wire_bytes_per_device=wire,
-            compute_s=cost["flops"] / roofline.PEAK_FLOPS,
-            memory_s=cost["bytes accessed"] / roofline.HBM_BW,
-            collective_s=wire / roofline.LINK_BW,
+            compute_s=cost["flops"] / chip.flops,
+            memory_s=cost["bytes accessed"] / chip.hbm_bw,
+            collective_s=wire / chip.link_bw,
             model_flops_global=mf,
             collectives=coll, peak_memory_bytes=peak)
         result = rep.to_dict()
@@ -333,7 +334,8 @@ def main() -> None:
                     if r.get("skipped"):
                         print(f"[SKIP] {cell}: {r['reason']}", flush=True)
                     else:
-                        print(f"[OK]   {cell}: compile={r['compile_s']:.1f}s "
+                        print(f"[OK]   {cell}: cpu compile="
+                              f"{r['compile_s']:.1f}s target={TARGET_KIND} "
                               f"dominant={r['dominant']} "
                               f"comp={r['compute_s']*1e3:.2f}ms "
                               f"mem={r['memory_s']*1e3:.2f}ms "

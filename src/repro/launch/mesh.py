@@ -1,11 +1,30 @@
-"""Production mesh builders.
+"""Mesh builders.
 
-A FUNCTION, not a module-level constant, so importing this module never
+FUNCTIONS, not module-level constants, so importing this module never
 touches jax device state (dry-runs must set XLA_FLAGS first).
+
+Every axis is ``AxisType.Auto``: the model code leaves placement to GSPMD
+(sharding constraints on activations, replicated params over the data
+axes), which is what ``jax.make_mesh``'s default of explicit axes would
+refuse — e.g. an embedding gather whose out-sharding is ambiguous.
 """
 from __future__ import annotations
 
+from typing import Optional, Sequence, Tuple
+
 import jax
+import numpy as np
+from jax.sharding import AxisType, Mesh
+
+
+def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...],
+              devices: Optional[Sequence] = None) -> Mesh:
+    """``jax.make_mesh`` with auto-sharded axes; ``devices`` defaults to
+    all of ``jax.devices()`` and may be described (compile-only) devices."""
+    auto = (AxisType.Auto,) * len(axes)
+    if devices is None:
+        return jax.make_mesh(shape, axes, axis_types=auto)
+    return Mesh(np.asarray(devices).reshape(shape), axes, axis_types=auto)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -20,17 +39,15 @@ def make_production_mesh(*, multi_pod: bool = False):
     for s in shape:
         need *= s
     devices = jax.devices()
+    if len(devices) < need:
+        raise RuntimeError(f"mesh needs {need} devices, have {len(devices)}")
     if len(devices) != need:
-        import numpy as np
-        if len(devices) < need:
-            raise RuntimeError(f"mesh needs {need} devices, have {len(devices)}")
-        from jax.sharding import Mesh
-        return Mesh(np.array(devices[:need]).reshape(shape), axes)
-    return jax.make_mesh(shape, axes)
+        return make_mesh(shape, axes, devices[:need])
+    return make_mesh(shape, axes)
 
 
 def make_debug_mesh(data: int = 4, model: int = 2, *, pods: int = 0):
     """Small mesh for subprocess tests (needs matching fake device count)."""
     if pods:
-        return jax.make_mesh((pods, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return make_mesh((pods, data, model), ("pod", "data", "model"))
+    return make_mesh((data, model), ("data", "model"))

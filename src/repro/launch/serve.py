@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCH_IDS, get_config, get_smoke
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.serve.serve_step import generate
 
@@ -29,6 +30,7 @@ def main() -> None:
     ap.add_argument("--sample", default="greedy", choices=["greedy", "temp"])
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = (get_smoke if args.preset == "smoke" else get_config)(args.arch)
     cfg = cfg.scaled(remat=False)

@@ -25,12 +25,16 @@ overlapped with the data plane instead of serialized in front of it.
 Usage (CPU example, ~1 minute):
   PYTHONPATH=src python -m repro.launch.train --arch qwen2-72b \
       --preset smoke --steps 30 --global-batch 16 --seq-len 64
+
+``run(args)`` is the whole driver and returns what it observed (per-step
+losses and times, the step's compile time); ``chip_smoke.py`` calls it at
+a published width with ``--preset full --layers N``.
 """
 from __future__ import annotations
 
 import argparse
-import os
 import time
+from typing import Callable, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -45,6 +49,7 @@ from repro.fanstore.metrics import JsonlSink, Reduce
 from repro.fanstore.prefetch import EpochSchedule, SchedulerGroup
 from repro.fanstore.spec import ClusterSpec
 from repro.fanstore.prepare import prepare_dataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.train.checkpoint import (CheckpointManager, restore_checkpoint,
                                     save_to_session)
@@ -52,10 +57,15 @@ from repro.train.optimizer import OptimizerConfig
 from repro.train.train_step import init_state, make_train_step
 
 
-def main() -> None:
+def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-72b", choices=ARCH_IDS)
     ap.add_argument("--preset", default="smoke", choices=["smoke", "full"])
+    ap.add_argument("--layers", type=int, default=0,
+                    help="cut the model to this many layers, widths "
+                         "untouched (0 = the preset's depth); global-"
+                         "attention layers stay first, evenly spaced and "
+                         "last")
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--global-batch", type=int, default=16)
     ap.add_argument("--seq-len", type=int, default=64)
@@ -119,7 +129,7 @@ def main() -> None:
                          "oracle stays exact at the seam; --steps is then "
                          "derived as epochs * steps_per_epoch "
                          "(0 = single-epoch schedule, --steps drives)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.epochs:
         if not args.prefetch_schedule:
             raise SystemExit("--epochs requires --prefetch-schedule "
@@ -127,8 +137,20 @@ def main() -> None:
         # derive the step budget up front so the optimizer schedule and
         # the stitched EpochSchedule agree on the horizon
         args.steps = args.epochs * (args.num_samples // args.global_batch)
+    return args
 
+
+def run(args: argparse.Namespace,
+        on_batch: Optional[Callable[[int, Dict], None]] = None) -> Dict:
+    """Train as ``args`` say; ``on_batch(step, batch)`` sees every batch
+    the loader hands to the step, before the step runs.
+
+    Returns ``losses`` and ``step_s`` (one per step run, each timed until
+    the loss is on the host) and ``compile_s`` of the train step.
+    """
     cfg = (get_smoke if args.preset == "smoke" else get_config)(args.arch)
+    if args.layers:
+        cfg = cfg.with_depth(args.layers)
     if cfg.family in ("audio", "vlm"):
         raise SystemExit("driver demo supports LM-batch families; "
                          "see examples/ for audio/vlm smoke paths")
@@ -260,30 +282,40 @@ def main() -> None:
         sampler.state.epoch = manifest["extra"].get("sampler_epoch", 0)
         print(f"resumed from step {start_step}")
 
-    step_fn = jax.jit(make_train_step(model, ocfg,
-                                      microbatches=args.microbatches))
-    t0 = time.perf_counter()
+    # the state is donated: each step's new state takes the old one's HBM
+    batch_shape = {"tokens": jax.ShapeDtypeStruct(
+        (args.global_batch, args.seq_len), jnp.int32)}
+    losses: List[float] = []
+    step_s: List[float] = []
     n_done = start_step
-    t_step = t0
     try:
+        t_compile = time.perf_counter()
+        step_fn = jax.jit(make_train_step(model, ocfg,
+                                          microbatches=args.microbatches),
+                          donate_argnums=0).lower(state, batch_shape).compile()
+        compile_s = time.perf_counter() - t_compile
+        t0 = time.perf_counter()
         for batch in loader.batches(args.steps - start_step):
+            if on_batch is not None:
+                on_batch(n_done, batch)
+            t_step = time.perf_counter()
             state, metrics = step_fn(state, batch)
+            losses.append(float(metrics["loss"]))
+            step_s.append(time.perf_counter() - t_step)
             n_done += 1
             if sink is not None:
-                now = time.perf_counter()
                 cm = cluster.metrics
-                cm.record_metric("train.loss", float(metrics["loss"]),
+                cm.record_metric("train.loss", losses[-1],
                                  reduce=Reduce.MEAN)
-                cm.record_metric("train.step_time_s", now - t_step,
+                cm.record_metric("train.step_time_s", step_s[-1],
                                  reduce=Reduce.P99)
                 cm.record_metric("train.items", args.global_batch,
                                  rate=True)
-                t_step = now
                 sink.tick(cm)
             if n_done % 10 == 0 or n_done == args.steps:
                 dt = time.perf_counter() - t0
                 items = (n_done - start_step) * args.global_batch / dt
-                print(f"step {n_done:5d} loss={float(metrics['loss']):.4f} "
+                print(f"step {n_done:5d} loss={losses[-1]:.4f} "
                       f"lr={float(metrics['lr']):.2e} "
                       f"throughput={items:.1f} items/s", flush=True)
             if n_done % args.ckpt_every == 0:
@@ -340,6 +372,13 @@ def main() -> None:
         print(f"fanstore-ckpt: write_bytes={clock.write_bytes} "
               f"write_s={clock.write_s:.6f} consume_s={clock.consume_s:.6f} "
               f"(write lane overlaps the data plane; busy={clock.busy_s:.6f})")
+    return {"losses": losses, "step_s": step_s, "compile_s": compile_s}
+
+
+def main() -> None:
+    args = parse_args()
+    enable_compile_cache()
+    run(args)
 
 
 if __name__ == "__main__":

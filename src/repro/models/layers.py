@@ -195,7 +195,9 @@ def flash_attention_lax(q, k, v, *, causal: bool = True,
     init = (jnp.full((b, nq, block_q, kvh, g), -1e30, jnp.float32),
             jnp.zeros((b, nq, block_q, kvh, g), jnp.float32),
             jnp.zeros((b, nq, block_q, kvh, g, dv), jnp.float32))
-    (m, l, acc), _ = lax.scan(kv_step, init, jnp.arange(nk))
+    # Remat each kv block: the backward pass keeps the (m, l, acc) carries
+    # and rebuilds one block's scores at a time, not all nk of them.
+    (m, l, acc), _ = lax.scan(jax.checkpoint(kv_step), init, jnp.arange(nk))
     out = acc / jnp.maximum(l, 1e-30)[..., None]
     out = out.reshape(b, nq * block_q, kvh * g, dv)[:, :tq]
     return out.astype(q.dtype)

@@ -6,10 +6,11 @@ x -> in_proj -> (u, z); u -> causal depthwise conv -> silu -> selective scan
 Selective scan: h_t = exp(dt_t * A) * h_{t-1} + dt_t * B_t * u_t,
 y_t = C_t . h_t + D * u_t, with diagonal A (d_inner, d_state), input-dependent
 dt/B/C. Training uses a chunked scan: lax.scan over time chunks with an
-associative scan inside each chunk — O(chunk * d_inner * d_state) peak
-memory. The Pallas kernel (repro.kernels.ssm_scan) implements the same
-chunking with explicit VMEM tiles; this module is the lowering-friendly
-reference used by dry-runs and CPU tests.
+associative scan inside each chunk, rematerialized per chunk in the
+backward pass — O(chunk * d_inner * d_state) peak memory. The Pallas
+kernel (repro.kernels.ssm_scan) implements the same chunking with explicit
+VMEM tiles; this module is the lowering-friendly path the models train
+with, on every backend.
 
 Decode is the O(1) recurrence on a carried (h, conv window) state — this is
 why falcon-mamba/hymba run the long_500k shape while full-attention archs
@@ -118,7 +119,11 @@ def selective_scan(u, dt, b_in, c_in, a_log, d_skip, h0=None, *,
         y = jnp.einsum("bqds,bqs->bqd", hseq, cq.astype(jnp.float32))
         return hseq[:, -1], y
 
-    h_final, yc = lax.scan(step, h0, (uc, dtc, bc, cc))
+    # Remat each chunk: the backward pass then keeps only the chunk-boundary
+    # states and rebuilds one chunk's (B, Q, di, st) scan at a time, instead
+    # of holding every chunk's at once (at T=4096 that alone is several
+    # times a 16 GiB chip's HBM).
+    h_final, yc = lax.scan(jax.checkpoint(step), h0, (uc, dtc, bc, cc))
     y = yc.swapaxes(0, 1).reshape(bsz, nt * chunk, di)[:, :t]
     y = y + u.astype(jnp.float32)[:, :y.shape[1]][:, :t] * d_skip.astype(jnp.float32)
     return y, h_final

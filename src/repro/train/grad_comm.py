@@ -25,8 +25,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.utils.compat import shard_map
-
 
 def quantize_ef(x: jnp.ndarray, residual: Optional[jnp.ndarray], *,
                 axis: int = -1) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
@@ -95,9 +93,9 @@ def make_compressed_psum(mesh: Mesh, axes: Tuple[str, ...]):
         total = (qg.astype(jnp.float32) * sg).reshape(-1)[:n]
         return total / world, new_res.reshape(-1)[:n]
 
-    return shard_map(local_fn, mesh=mesh,
-                     in_specs=(P(), P()), out_specs=(P(), P()),
-                     check_vma=False)
+    return jax.shard_map(local_fn, mesh=mesh,
+                         in_specs=(P(), P()), out_specs=(P(), P()),
+                         check_vma=False)
 
 
 def compressed_psum(grads: Any, residual: Any, mesh: Mesh,

@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.utils.compat import shard_map
 from repro.train.grad_comm import make_compressed_psum, _flatten_grads, \
     _unflatten_grads
 from repro.train.optimizer import OptimizerConfig, adamw_init, adamw_update
@@ -156,7 +155,7 @@ def make_train_step(model, opt_cfg: OptimizerConfig, *,
             opt=jax.tree.map(lambda _: P(), state.opt),
             ef=P())
         batch_specs = {k: P(dp_axes) for k in batch}
-        out = shard_map(
+        out = jax.shard_map(
             local_step, mesh=mesh,
             in_specs=(state_specs, batch_specs),
             out_specs=(state_specs, {"loss": P(), "grad_norm": P(), "lr": P()}),

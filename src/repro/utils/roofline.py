@@ -1,8 +1,11 @@
-"""Roofline terms from a compiled dry-run artifact (TPU v5e constants).
+"""Roofline terms from a compiled dry-run artifact.
 
   compute term    = HLO_FLOPs / peak_FLOPs            (per device)
   memory term     = HLO_bytes / HBM_bw                (per device)
   collective term = wire_bytes / link_bw              (per device)
+
+Peaks come from one table keyed by ``device_kind`` (as JAX reports it);
+a kind that is not in the table is an error, never a default.
 
 cost_analysis() on the SPMD-partitioned module reports per-device FLOPs and
 bytes. Collective wire bytes are parsed from the partitioned HLO text:
@@ -14,11 +17,32 @@ from __future__ import annotations
 
 import re
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, Optional
 
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # bytes/s / chip
-LINK_BW = 50e9               # bytes/s / link (one active ICI link, conservative)
+
+@dataclass(frozen=True)
+class ChipPeaks:
+    flops: float          # bf16 FLOP/s per chip
+    hbm_bw: float         # HBM bytes/s per chip
+    link_bw: float        # bytes/s over one ICI link
+
+
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM,
+# 1,600 Gbit/s of ICI per chip over 4 links (50 GB/s per link, the
+# conservative one-active-link figure the collective term uses).
+PEAKS: Dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(flops=197e12, hbm_bw=819e9, link_bw=50e9),
+}
+
+
+def peaks(device_kind: str) -> ChipPeaks:
+    """Published peaks of one chip of ``device_kind``; unknown kinds raise."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "s32": 4, "u32": 4,
@@ -126,6 +150,7 @@ class RooflineReport:
     shape: str
     mesh: str
     chips: int
+    device_kind: str
     flops_per_device: float
     bytes_per_device: float
     wire_bytes_per_device: float
@@ -157,12 +182,13 @@ class RooflineReport:
         t = self.step_time_lower_bound_s
         if t <= 0:
             return 0.0
-        return self.model_flops_global / (self.chips * PEAK_FLOPS * t)
+        return self.model_flops_global / (
+            self.chips * peaks(self.device_kind).flops * t)
 
     def to_dict(self) -> Dict:
         return {
             "arch": self.arch, "shape": self.shape, "mesh": self.mesh,
-            "chips": self.chips,
+            "chips": self.chips, "device_kind": self.device_kind,
             "flops_per_device": self.flops_per_device,
             "bytes_per_device": self.bytes_per_device,
             "wire_bytes_per_device": self.wire_bytes_per_device,
@@ -176,21 +202,3 @@ class RooflineReport:
             "peak_memory_bytes": self.peak_memory_bytes,
         }
 
-
-def build_report(*, arch: str, shape: str, mesh_name: str, chips: int,
-                 cost: Dict, hlo_text: str, model_flops_global: float,
-                 peak_memory: Optional[float] = None) -> RooflineReport:
-    flops = float(cost.get("flops", 0.0))
-    nbytes = float(cost.get("bytes accessed", 0.0))
-    stats = parse_collectives(hlo_text)
-    return RooflineReport(
-        arch=arch, shape=shape, mesh=mesh_name, chips=chips,
-        flops_per_device=flops, bytes_per_device=nbytes,
-        wire_bytes_per_device=stats.wire_bytes,
-        compute_s=flops / PEAK_FLOPS,
-        memory_s=nbytes / HBM_BW,
-        collective_s=stats.wire_bytes / LINK_BW,
-        model_flops_global=model_flops_global,
-        collectives=dict(stats.bytes_by_kind),
-        peak_memory_bytes=peak_memory,
-    )

@@ -81,7 +81,8 @@ def test_pipeline_parallel_matches_serial():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import numpy as np, jax, jax.numpy as jnp
         from repro.dist.pipeline_par import pipeline_apply, split_stages
-        mesh = jax.make_mesh((4,), ("stage",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("stage",))
         L, D, B = 8, 16, 8
         rng = np.random.default_rng(0)
         Ws = jnp.asarray(rng.standard_normal((L, D, D)) / np.sqrt(D))
@@ -102,6 +103,7 @@ def test_pipeline_parallel_matches_serial():
         print("OK")
     """
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                          capture_output=True, text=True, env=env, timeout=480)

@@ -58,10 +58,10 @@ def test_dryrun_cell_small_mesh():
     code = """
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-        import jax
         from repro.launch.dryrun import lower_cell, _mem_dict, _cell_costs
+        from repro.launch.mesh import make_debug_mesh
         from repro.configs import get_smoke
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_debug_mesh(4, 2)
         cfg = get_smoke("chatglm3-6b")
         # reduced shapes: monkeypatch the shape table for the subprocess
         import repro.configs.base as base
@@ -75,6 +75,7 @@ def test_dryrun_cell_small_mesh():
         print("OK", int(costs["flops"]))
     """
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                          capture_output=True, text=True, env=env, timeout=480)
@@ -83,6 +84,54 @@ def test_dryrun_cell_small_mesh():
 
 
 def test_mesh_factories():
+    from jax.sharding import AxisType
     from repro.launch.mesh import make_debug_mesh
     m = make_debug_mesh(1, 1)
     assert m.axis_names == ("data", "model")
+    assert m.axis_types == (AxisType.Auto, AxisType.Auto)
+
+
+@pytest.mark.parametrize("arch,layers,want", [
+    ("hymba-1.5b", 8, (0, 3, 7)),       # published (0, 15, 31) of 32
+    ("hymba-1.5b", 32, (0, 15, 31)),
+    ("hymba-1.5b", 1, (0,)),
+    ("chatglm3-6b", 2, ()),
+])
+def test_with_depth_keeps_widths_and_global_layers(arch, layers, want):
+    cfg = get_config(arch)
+    cut = cfg.with_depth(layers)
+    assert cut.num_layers == layers
+    assert cut.global_layers == want
+    assert cut.scaled(num_layers=cfg.num_layers,
+                      global_layers=cfg.global_layers) == cfg
+
+
+def test_roofline_peaks_by_device_kind():
+    from repro.utils.roofline import peaks
+    v5e = peaks("TPU v5 lite")
+    assert (v5e.flops, v5e.hbm_bw) == (197e12, 819e9)
+    with pytest.raises(ValueError, match="no published peaks"):
+        peaks("cpu")
+
+
+@pytest.mark.parametrize("env_dir", [None, "/elsewhere/jax-cache"])
+def test_compile_cache_dir(monkeypatch, env_dir):
+    import jax
+    from repro.launch import compile_cache
+    was = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    else:
+        monkeypatch.setenv(compile_cache.ENV_VAR, env_dir)
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        got = compile_cache.enable_compile_cache()
+        if env_dir is None:
+            assert got == os.path.join(ROOT, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+        else:
+            # JAX reads the variable itself; nothing is set in code
+            assert got == env_dir
+            assert jax.config.jax_compilation_cache_dir is None
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)

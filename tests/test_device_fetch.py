@@ -15,6 +15,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def run_in_subprocess(code: str) -> str:
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
@@ -27,7 +28,8 @@ def test_fetch_uniform_and_overflow():
     print(run_in_subprocess("""
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import DeviceStore, DeviceStoreConfig, tokens_from_payload
-        mesh = jax.make_mesh((4,2), ("data","model"))
+        from repro.launch.mesh import make_debug_mesh
+        mesh = make_debug_mesh(4, 2)
         S, L, G = 64, 8, 16
         tokens = np.arange(S*L, dtype=np.int32).reshape(S, L)
         rng = np.random.default_rng(0)
@@ -57,7 +59,8 @@ def test_fetch_stratified_zero_waste():
         import numpy as np, jax
         from repro.core import DeviceStore, DeviceStoreConfig, tokens_from_payload
         from repro.data.sampler import StratifiedSampler
-        mesh = jax.make_mesh((4,2), ("data","model"))
+        from repro.launch.mesh import make_debug_mesh
+        mesh = make_debug_mesh(4, 2)
         S, L, G = 128, 8, 32
         tokens = np.arange(S*L, dtype=np.int32).reshape(S, L)
         samp = StratifiedSampler(S, G, num_shards=4, seed=1)
@@ -80,7 +83,8 @@ def test_fetch_multi_pod_and_replication():
     print(run_in_subprocess("""
         import numpy as np, jax
         from repro.core import DeviceStore, DeviceStoreConfig, tokens_from_payload
-        mesh = jax.make_mesh((2,2,2), ("pod","data","model"))
+        from repro.launch.mesh import make_debug_mesh
+        mesh = make_debug_mesh(2, 2, pods=2)
         S, L, G = 64, 8, 16
         tokens = np.arange(S*L, dtype=np.int32).reshape(S, L)
         rng = np.random.default_rng(3)
@@ -105,7 +109,8 @@ def test_fetch_dequant_pipeline():
         from repro.core import DeviceStore, DeviceStoreConfig
         from repro.core.codec import block_quantize, block_dequantize_host
         from repro.kernels import ops
-        mesh = jax.make_mesh((4,2), ("data","model"))
+        from repro.launch.mesh import make_debug_mesh
+        mesh = make_debug_mesh(4, 2)
         S, F = 32, 512
         rng = np.random.default_rng(0)
         x = rng.standard_normal((S, F)).astype(np.float32)
@@ -137,7 +142,8 @@ def test_int8_grad_sync_matches_fp32():
         from repro.models import build_model
         from repro.train.optimizer import OptimizerConfig
         from repro.train.train_step import make_train_step, init_state
-        mesh = jax.make_mesh((4,2), ("data","model"))
+        from repro.launch.mesh import make_debug_mesh
+        mesh = make_debug_mesh(4, 2)
         cfg = get_smoke("chatglm3-6b").scaled(remat=False)
         model = build_model(cfg)
         ocfg = OptimizerConfig(lr=5e-3, warmup_steps=1, total_steps=40)

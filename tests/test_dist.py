@@ -13,13 +13,14 @@ from jax.sharding import PartitionSpec as P
 
 from repro.dist.pipeline_par import split_stages
 from repro.dist.sharding import ShardingRules, make_rules
+from repro.launch.mesh import make_debug_mesh
 
 
 @pytest.fixture
 def mesh():
     # a 1-device mesh still carries named axes of size 1; for spec-selection
     # tests we need real sizes, so fake them via a 1x1 mesh + explicit rules
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_debug_mesh(1, 1)
 
 
 class _FakeMesh:

@@ -43,6 +43,26 @@ def test_end_to_end_fanstore_training(rng):
     assert cluster.local_hit_rate() > 0.3       # replication=2 on 4 nodes
 
 
+def test_train_run_feeds_reference_batches():
+    """launch/train.run: the loader hands the step byte-identical batches
+    in the sampler's order, and every loss is finite."""
+    from repro.launch import train
+    args = train.parse_args([
+        "--arch", "hymba-1.5b", "--preset", "smoke", "--layers", "2",
+        "--seq-len", "16", "--global-batch", "4", "--num-samples", "32",
+        "--steps", "3", "--seed", "3"])
+    seen = {}
+    out = train.run(args, on_batch=lambda i, b: seen.setdefault(
+        i, np.asarray(b["tokens"])))
+    tokens = token_dataset(32, 16, get_smoke("hymba-1.5b").vocab_size,
+                           seed=3)
+    sampler = GlobalUniformSampler(32, 4, seed=3)
+    for i in range(3):
+        assert seen[i].tobytes() == tokens[sampler.next_batch()].tobytes()
+    assert len(out["losses"]) == len(out["step_s"]) == 3
+    assert np.isfinite(out["losses"]).all() and out["compile_s"] > 0
+
+
 def test_microbatching_equivalence(rng):
     """2-way grad accumulation == single big batch (same loss trajectory)."""
     cfg = get_smoke("qwen2-72b").scaled(remat=False)
@@ -98,7 +118,8 @@ def test_zero1_shardings_api():
     from repro.train.optimizer import zero1_leaf_sharding
     import jax.sharding as shd
     # single-device "mesh" exercise of the spec logic
-    mesh = jax.make_mesh((1,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("data",))
     fn = zero1_leaf_sharding(mesh, ("data",))
     ns = shd.NamedSharding(mesh, shd.PartitionSpec(None, None))
     leaf = jax.ShapeDtypeStruct((8, 4), jnp.float32)
