@@ -119,9 +119,17 @@ def run_one(nodes: int, file_size: int, count: int,
         m = min(reads_per_node, count)
         cache_mb = (m * file_size) // (1024 * 1024) + 1
     if cluster is None:
-        cluster = _build_cluster(nodes, file_size, count, net,
-                                 replication=replication, cache_mb=cache_mb,
-                                 cache_policy=cache_policy)
+        # a cluster built here is closed here: its pool threads are joined
+        # before the caller goes on, not whenever the collector frees it
+        with _build_cluster(nodes, file_size, count, net,
+                            replication=replication, cache_mb=cache_mb,
+                            cache_policy=cache_policy) as owned:
+            return run_one(nodes, file_size, count, net,
+                           replication=replication,
+                           reads_per_node=reads_per_node, batched=batched,
+                           prefetch=prefetch, window=window,
+                           cache_mb=cache_mb, cache_policy=cache_policy,
+                           epochs=epochs, cluster=owned)
     paths = sorted(f"bench/f_{i:06d}.bin" for i in range(count))
     cluster.reset_clocks()
     cluster.clear_caches()

@@ -21,6 +21,8 @@ from typing import Callable, Iterator, List, Optional, Sequence
 
 import numpy as np
 
+from repro.fanstore.metrics import SPANS
+
 
 class EpochShuffler:
     """Deterministic per-epoch permutation utility (shared by samplers/tests)."""
@@ -60,6 +62,16 @@ class PrefetchLoader:
     Errors raised inside the producer thread are never swallowed: they
     surface on the next ``__next__`` (in place of further batches) or on
     ``close()`` if the consumer stopped early.
+
+    While :data:`repro.fanstore.metrics.SPANS` records, the producer's
+    ``fanstore.loader.fetch``, ``fanstore.loader.decode`` and
+    ``fanstore.loader.put_wait`` (time blocked on a full queue) and the
+    consumer's ``fanstore.loader.get`` (time waiting in ``__next__``,
+    counter ``starved`` when the queue was empty on arrival) share the
+    batch's id with the spans the fetch opens (``fanstore.read_many``).
+    While nothing records, a batch still takes an id from a counter, sets
+    it in a thread-local and goes through the queue as a ``(id, batch)``
+    pair; each of its four span sites is one flag check.
     """
 
     def __init__(self, sampler, fetch: Callable[[int], bytes] = None,
@@ -92,8 +104,14 @@ class PrefetchLoader:
 
     # -- batch assembly ------------------------------------------------------
     def _fetch_batch(self, indices: np.ndarray) -> object:
+        with SPANS.span("fanstore.loader.fetch"):
+            blobs = self._fetch_blobs(indices)
+        with SPANS.span("fanstore.loader.decode"):
+            return self.decode(blobs)
+
+    def _fetch_blobs(self, indices: np.ndarray) -> List[bytes]:
         if self.fetch_many is not None:
-            return self.decode(self.fetch_many([int(i) for i in indices]))
+            return self.fetch_many([int(i) for i in indices])
         out: List[Optional[bytes]] = [None] * len(indices)
         if self.num_threads <= 1:
             for i, idx in enumerate(indices):
@@ -102,8 +120,10 @@ class PrefetchLoader:
             cursor = iter(range(len(indices)))
             lock = threading.Lock()
             errors: List[BaseException] = []
+            batch_id = SPANS.batch()
 
             def worker():
+                SPANS.set_batch(batch_id)
                 while True:
                     with lock:
                         if errors:
@@ -126,7 +146,7 @@ class PrefetchLoader:
                 t.join()
             if errors:
                 raise errors[0]
-        return self.decode(out)  # type: ignore[arg-type]
+        return out  # type: ignore[return-value]
 
     def _produce(self, num_batches: int) -> None:
         try:
@@ -139,17 +159,23 @@ class PrefetchLoader:
                     self.schedule.ensure(
                         self._sched_step + self.prefetch_window)
                     self.schedule.wait_ready(self._sched_step)
+                batch_id = SPANS.next_batch()
+                SPANS.set_batch(batch_id)
                 batch = self._fetch_batch(self.sampler.next_batch())
                 self._sched_step += 1
-                while not self._stop.is_set():
-                    try:
-                        self._q.put(batch, timeout=0.1)
-                        break
-                    except queue.Full:
-                        continue
+                with SPANS.span("fanstore.loader.put_wait") as span:
+                    if span:
+                        span.add("full", int(self._q.full()))
+                    while not self._stop.is_set():
+                        try:
+                            self._q.put((batch_id, batch), timeout=0.1)
+                            break
+                        except queue.Full:
+                            continue
         except BaseException as e:   # surfaced on the consumer side
             self._err = e
         finally:
+            SPANS.set_batch(None)
             self._q.put(None)
 
     # -- public API ------------------------------------------------------------
@@ -176,7 +202,12 @@ class PrefetchLoader:
         if self._done:
             self._raise_pending()
             raise StopIteration
-        item = self._q.get()
+        with SPANS.span("fanstore.loader.get") as span:
+            if span:
+                span.add("starved", int(self._q.empty()))
+            item = self._q.get()
+            if span and item is not None:
+                span.batch = item[0]
         if item is None:
             self._done = True
             self._producer.join()
@@ -184,7 +215,7 @@ class PrefetchLoader:
                 self.schedule.close()    # surfaces in-flight window errors
             self._raise_pending()
             raise StopIteration
-        return item
+        return item[1]
 
     def batches(self, num_batches: int) -> Iterator[object]:
         """Yield ``num_batches`` decoded batches with prefetch overlap."""

@@ -46,6 +46,7 @@ from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 from repro.fanstore.accounting import NodeClock, WallClock, WindowAccount
+from repro.fanstore.metrics import SPANS
 from repro.fanstore.store import NodeStore
 from repro.fanstore.wire import FetchItem, WireCodecPolicy
 
@@ -310,11 +311,20 @@ class TransportBackend:
         """
         if not items:
             return []
-        out = self._timed_fetch(requester, owner, items, materialize,
-                                "fetch_batch", lane)
-        with self._lock:
-            self._account_remote(requester, owner, items, round_trips=1,
-                                 lane=lane, tenant=tenant)
+        with SPANS.span("fanstore.fetch.remote") as span:
+            out = self._timed_fetch(requester, owner, items, materialize,
+                                    "fetch_batch", lane)
+            if span:
+                t0 = time.perf_counter_ns()
+            with self._lock:
+                self._account_remote(requester, owner, items, round_trips=1,
+                                     lane=lane, tenant=tenant)
+            if span:
+                # the modeled-cost bookkeeping, waiting for the lock included
+                span.counters.update(
+                    files=len(items),
+                    bytes=sum(it.stored for it in items),
+                    account_ns=time.perf_counter_ns() - t0)
         return out
 
     def fetch_window(self, requester: int, owner: int,

@@ -44,12 +44,14 @@ and elastic membership hooks (see repro.train.elastic for the planner).
 from __future__ import annotations
 
 import threading
+import time
 from concurrent.futures import Future
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from repro.fanstore.accounting import ClusterAccounting, NodeClock
 from repro.fanstore.cache import ByteCache, NodeCacheTier
 from repro.fanstore.layout import iter_partition, pack_partition
+from repro.fanstore.metrics import SPANS
 from repro.fanstore.metadata import (FileLocation, MetadataTable, StatRecord,
                                      modulo_placement, path_hash)
 from repro.fanstore.placement import Placement, ReplicaSelector
@@ -539,9 +541,10 @@ class FanStoreCluster:
             int, List[Tuple[int, FetchItem, FileLocation]]], *,
             materialize: bool, batched: bool, window: bool,
             on_data, lost_ok: bool, lane: str = "consume",
-            tenant: Optional[str] = None) -> None:
+            tenant: Optional[str] = None) -> Tuple[int, int]:
         """Drain an (owner -> [(slot, item, loc)]) worklist, classifying
-        owner errors and retrying on the next live replica.
+        owner errors and retrying on the next live replica. Returns the
+        round trips that succeeded and the retries paid.
 
         One round fetches every group; a group whose owner raised a
         transport failure (ConnectionError / timeout / ERR frame /
@@ -566,6 +569,7 @@ class FanStoreCluster:
         from repro.fanstore.faults import NodeLostError, is_transport_failure
         attempt = 0
         max_attempts = (self.fault_threshold + 1) * max(2, len(self.nodes))
+        trips = retries = 0
         while groups:
             attempt += 1
             failed: List[Tuple[
@@ -593,6 +597,7 @@ class FanStoreCluster:
                     failed.append((owner, entries, exc))
                     continue
                 self._note_owner_ok(owner)
+                trips += 1 if window or batched else len(items)
                 del groups[owner]
                 for (slot, item, _), data in zip(entries, datas):
                     on_data(slot, item, data)
@@ -601,6 +606,7 @@ class FanStoreCluster:
             # one retry tick per failed group, one shared backoff level
             self._retry_backoff(requester, min(attempt, 16),
                                 count=len(failed))
+            retries += len(failed)
             last_exc = failed[-1][2]
             regroup: Dict[int, List[
                 Tuple[int, FetchItem, FileLocation]]] = {}
@@ -619,6 +625,7 @@ class FanStoreCluster:
             if lost and not lost_ok:
                 raise NodeLostError.for_items(lost) from last_exc
             groups = regroup
+        return trips, retries
 
     def read(self, requester: int, path: str, *, worker_id: int = 0,
              materialize: bool = True, lane: str = "consume",
@@ -672,52 +679,75 @@ class FanStoreCluster:
         # next live replica without a second metadata pass
         groups: Dict[int, List[Tuple[int, FetchItem, FileLocation]]] = {}
         pending_serve: Dict[int, float] = {}
-        for i, raw in enumerate(paths):
-            path = raw.strip("/")
-            st, loc = self._lookup(path)
-            item = self._fetch_item(path, st, loc)
-            if tier.enabled:
-                entry = tier.get(path, worker_id=worker_id,
-                                 require_data=materialize, job=job)
-                if entry is not None:
-                    self.transport.account_cache_hit(requester, item,
-                                                     worker_id=worker_id,
-                                                     lane=lane, tenant=tenant,
-                                                     job=job)
-                    out[i] = entry.data if materialize else b""
-                    continue
-                self.transport.account_cache_miss(requester,
-                                                  worker_id=worker_id,
-                                                  job=job)
-            if self.nodes[requester].has(path) or \
-                    self.nodes[requester].has_output(path):
-                data = self.transport.fetch_local(requester, item,
-                                                  materialize=materialize,
-                                                  lane=lane, tenant=tenant)
-                out[i] = data
+        # the span's self time is the plan (metadata, placement, cache
+        # tier); local copies are timed apart, as local_ns, and the remote
+        # leg is its child span
+        span = SPANS.span("fanstore.read_many")
+        timed = bool(span)
+        hits = files_local = bytes_local = local_ns = 0
+        with span:
+            for i, raw in enumerate(paths):
+                path = raw.strip("/")
+                st, loc = self._lookup(path)
+                item = self._fetch_item(path, st, loc)
                 if tier.enabled:
-                    ev = tier.put(path, data if materialize else None,
+                    entry = tier.get(path, worker_id=worker_id,
+                                     require_data=materialize, job=job)
+                    if entry is not None:
+                        self.transport.account_cache_hit(
+                            requester, item, worker_id=worker_id,
+                            lane=lane, tenant=tenant, job=job)
+                        out[i] = entry.data if materialize else b""
+                        hits += 1
+                        continue
+                    self.transport.account_cache_miss(requester,
+                                                      worker_id=worker_id,
+                                                      job=job)
+                if self.nodes[requester].has(path) or \
+                        self.nodes[requester].has_output(path):
+                    if timed:
+                        t0 = time.perf_counter_ns()
+                    data = self.transport.fetch_local(
+                        requester, item, materialize=materialize,
+                        lane=lane, tenant=tenant)
+                    if timed:
+                        local_ns += time.perf_counter_ns() - t0
+                        files_local += 1
+                        bytes_local += item.size
+                    out[i] = data
+                    if tier.enabled:
+                        ev = tier.put(path, data if materialize else None,
+                                      size=item.size, worker_id=worker_id,
+                                      job=job)
+                        self.transport.account_cache_eviction(requester, ev)
+                    continue
+                owner = self._choose_owner(loc, item, pending_serve)
+                if owner is None:
+                    raise NodeLostError.for_items([(path, loc.partition_id)])
+                groups.setdefault(owner, []).append((i, item, loc))
+
+            def deliver(slot: int, item: FetchItem, data: bytes) -> None:
+                out[slot] = data
+                if tier.enabled:
+                    ev = tier.put(item.path, data if materialize else None,
                                   size=item.size, worker_id=worker_id,
                                   job=job)
                     self.transport.account_cache_eviction(requester, ev)
-                continue
-            owner = self._choose_owner(loc, item, pending_serve)
-            if owner is None:
-                raise NodeLostError.for_items([(path, loc.partition_id)])
-            groups.setdefault(owner, []).append((i, item, loc))
 
-        def deliver(slot: int, item: FetchItem, data: bytes) -> None:
-            out[slot] = data
-            if tier.enabled:
-                ev = tier.put(item.path, data if materialize else None,
-                              size=item.size, worker_id=worker_id,
-                              job=job)
-                self.transport.account_cache_eviction(requester, ev)
-
-        self._fetch_with_failover(requester, groups,
-                                  materialize=materialize, batched=batched,
-                                  window=False, on_data=deliver,
-                                  lost_ok=False, lane=lane, tenant=tenant)
+            if timed:
+                span.counters.update(
+                    files_local=files_local, bytes_local=bytes_local,
+                    local_ns=local_ns, cache_hits=hits,
+                    files_remote=sum(map(len, groups.values())),
+                    bytes_remote=sum(it.stored for entries in groups.values()
+                                     for _, it, _ in entries))
+            with SPANS.span("fanstore.read_many.remote"):
+                trips, retries = self._fetch_with_failover(
+                    requester, groups, materialize=materialize,
+                    batched=batched, window=False, on_data=deliver,
+                    lost_ok=False, lane=lane, tenant=tenant)
+            if timed:
+                span.counters.update(owners=trips, retries=retries)
         return out  # type: ignore[return-value]
 
     def read_many_async(self, requester: int, paths: Sequence[str], *,

@@ -36,6 +36,13 @@ emit through now:
   cross-path :class:`Ref` comparisons, conditional ``when`` clauses).
   ``benchmarks/run.py`` expresses every BENCH_io.json guard as a table
   of these instead of assert soup.
+* :class:`SpanRecorder` / :data:`SPANS` — the program's own spans
+  (``fanstore.*``) inside the read path and the loader, on
+  ``time.perf_counter_ns``, each with its parent, thread, batch id and
+  integer counters, kept in a bounded ring. It records only while a JAX
+  profiler session is active (each span then also lands in the trace as
+  a ``jax.profiler.TraceAnnotation``) or while forced on;
+  :func:`fold_spans` folds recorded spans into a collector.
 
 Provenance discipline: everything under ``snapshot()["nodes"][i]
 ["modeled"]`` / ``["cluster"]`` modeled aggregates is deterministic
@@ -46,10 +53,13 @@ catalog in ARCHITECTURE.md).
 """
 from __future__ import annotations
 
+import collections
 import copy
 import enum
+import itertools
 import json
 import os
+import sys
 import threading
 import time
 import weakref
@@ -63,6 +73,7 @@ __all__ = [
     "RateAccumulator", "make_accumulator",
     "MetricsCollector", "JsonlSink",
     "SloGuard", "Ref", "check_slos", "resolve_path",
+    "Span", "SpanRecorder", "SPANS", "SPAN_PREFIX", "fold_spans",
 ]
 
 
@@ -512,6 +523,207 @@ class MetricsCollector:
         if sink is not None:
             sink.emit(snap)
         return snap
+
+
+# ---------------------------------------------------------------------------
+# program spans
+# ---------------------------------------------------------------------------
+SPAN_PREFIX = "fanstore."
+SPAN_CAPACITY = 1 << 18     # about 100 MB at most; a batch opens about 9
+
+
+class _SpanLocal(threading.local):
+    span: Optional["Span"] = None      # innermost open span on this thread
+    batch: Optional[int] = None        # batch the thread is working on
+
+
+class Span:
+    """One timed region of the program.
+
+    ``start_ns``/``end_ns`` are ``time.perf_counter_ns`` readings;
+    ``parent`` is the id of the span open around this one on the same
+    thread (None at the top), ``batch`` the batch id the thread was
+    working on when the span opened, ``counters`` integer counts. While
+    open it is also a ``jax.profiler.TraceAnnotation`` of the same name
+    (once JAX's profiler is imported), so it lands in a profiler trace on
+    the device trace's clock, with its counters as metadata.
+    """
+
+    __slots__ = ("name", "id", "parent", "thread", "batch", "start_ns",
+                 "end_ns", "counters", "_rec", "_up", "_ann")
+
+    def __init__(self, rec: "SpanRecorder", name: str):
+        self.name = name
+        self.id = next(rec._ids)
+        self.counters: Dict[str, int] = {}
+        self._rec = rec
+        self.start_ns = self.end_ns = 0
+
+    def add(self, key: str, n: int = 1) -> None:
+        self.counters[key] = self.counters.get(key, 0) + n
+
+    @property
+    def duration_ns(self) -> int:
+        return self.end_ns - self.start_ns
+
+    def __enter__(self) -> "Span":
+        local = self._rec._local
+        up = local.span
+        self._up = up
+        self.parent = up.id if up is not None else None
+        self.thread = threading.get_ident()
+        self.batch = local.batch
+        local.span = self
+        annotation = self._rec._annotation()
+        self._ann = annotation(self.name) if annotation is not None else None
+        if self._ann is not None:
+            self._ann.__enter__()
+        self.start_ns = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.end_ns = time.perf_counter_ns()
+        if self._ann is not None:
+            if self.counters:
+                self._ann.set_metadata(**self.counters)
+            self._ann.__exit__(*exc)
+            self._ann = None
+        self._rec._local.span = self._up
+        self._up = None
+        self._rec._push(self)
+        return False
+
+
+class _NullSpan:
+    """What a span site gets while nothing records: falsy, and shared."""
+
+    __slots__ = ()
+
+    def __bool__(self) -> bool:
+        return False
+
+    def __enter__(self) -> "_NullSpan":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+    def add(self, key: str, n: int = 1) -> None:
+        pass
+
+
+_NULL_SPAN = _NullSpan()
+
+
+class SpanRecorder:
+    """The process-wide recorder of program spans (:data:`SPANS`).
+
+    It records while a JAX profiler session is active
+    (``TraceAnnotation.is_enabled()``), unless ``forced`` says otherwise:
+    True records always, False never, None (the default) follows the
+    profiler. While it does not record, :meth:`span` costs one flag
+    check and returns a shared, falsy no-op span, so a site can guard
+    extra timing with ``if span:``. Recorded spans go into a ring of
+    ``capacity`` spans; when it is full the oldest is dropped,
+    ``dropped`` counts it and ``dropped_start_ns`` keeps the latest start
+    of a dropped span, so a reader can tell whether the spans it wants
+    are whole. Every name starts with ``fanstore.``.
+    """
+
+    def __init__(self, capacity: int = SPAN_CAPACITY):
+        if capacity < 1:
+            raise ValueError("span capacity must be >= 1")
+        self.capacity = int(capacity)
+        self.forced: Optional[bool] = None
+        self.dropped = 0
+        self.dropped_start_ns: Optional[int] = None
+        self._ring: "collections.deque[Span]" = collections.deque()
+        self._lock = threading.Lock()
+        self._local = _SpanLocal()
+        self._ids = itertools.count(1)
+        self._batch_ids = itertools.count(1)
+        self._trace_annotation = None
+
+    def _annotation(self):
+        """``jax.profiler.TraceAnnotation``, once something has imported
+        JAX's profiler (no profiler session can be active before)."""
+        annotation = self._trace_annotation
+        if annotation is None:
+            mod = sys.modules.get("jax.profiler")
+            if mod is not None:
+                annotation = self._trace_annotation = mod.TraceAnnotation
+        return annotation
+
+    def recording(self) -> bool:
+        forced = self.forced
+        if forced is not None:
+            return forced
+        annotation = self._annotation()
+        return annotation is not None and annotation.is_enabled()
+
+    def span(self, name: str):
+        """A context manager timing one region, or the no-op span."""
+        if not self.recording():
+            return _NULL_SPAN
+        if not name.startswith(SPAN_PREFIX):
+            raise ValueError(f"span name {name!r} must start with "
+                             f"{SPAN_PREFIX!r}")
+        return Span(self, name)
+
+    def next_batch(self) -> int:
+        """A batch id unique in the process (across loaders)."""
+        return next(self._batch_ids)
+
+    def set_batch(self, batch: Optional[int]) -> None:
+        """Tags the spans this thread opens from now on with ``batch``."""
+        self._local.batch = batch
+
+    def batch(self) -> Optional[int]:
+        """The batch id this thread's spans are tagged with."""
+        return self._local.batch
+
+    def _push(self, span: Span) -> None:
+        with self._lock:
+            if len(self._ring) >= self.capacity:
+                old = self._ring.popleft()
+                self.dropped += 1
+                if (self.dropped_start_ns is None
+                        or old.start_ns > self.dropped_start_ns):
+                    self.dropped_start_ns = old.start_ns
+            self._ring.append(span)
+
+    def spans(self, name: Optional[str] = None) -> List[Span]:
+        """A copy of the ring (oldest first), optionally of one name."""
+        with self._lock:
+            out = list(self._ring)
+        return out if name is None else [s for s in out if s.name == name]
+
+    def drain(self) -> List[Span]:
+        """Takes every recorded span out of the ring."""
+        with self._lock:
+            out = list(self._ring)
+            self._ring.clear()
+        return out
+
+    def clear(self) -> None:
+        with self._lock:
+            self._ring.clear()
+            self.dropped = 0
+            self.dropped_start_ns = None
+
+
+SPANS = SpanRecorder()
+
+
+def fold_spans(collector: MetricsCollector, spans: Iterable[Span]) -> None:
+    """Folds spans into ``collector``: per span name, its duration in ms
+    as ``<name>.ms`` (P99; the summary carries P50 too) and the sum of
+    each counter as ``<name>.<counter>``."""
+    for s in spans:
+        collector.record_metric(f"{s.name}.ms", s.duration_ns / 1e6,
+                                reduce=Reduce.P99)
+        for key, n in s.counters.items():
+            collector.record_metric(f"{s.name}.{key}", n, reduce=Reduce.SUM)
 
 
 # ---------------------------------------------------------------------------

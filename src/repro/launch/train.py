@@ -45,7 +45,7 @@ from repro.data.pipeline import PrefetchLoader
 from repro.data.sampler import GlobalUniformSampler, StratifiedSampler
 from repro.data.synthetic import files_to_tokens, token_dataset, tokens_to_files
 from repro.fanstore.cluster import FanStoreCluster
-from repro.fanstore.metrics import JsonlSink, Reduce
+from repro.fanstore.metrics import SPANS, JsonlSink, Reduce, fold_spans
 from repro.fanstore.prefetch import EpochSchedule, SchedulerGroup
 from repro.fanstore.spec import ClusterSpec
 from repro.fanstore.prepare import prepare_dataset
@@ -92,7 +92,8 @@ def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     ap.add_argument("--metrics-jsonl", default=None, metavar="PATH",
                     help="stream per-step training metrics (loss mean, "
                          "step-time p99, items/s rate, per-rank read "
-                         "bytes) plus the full accounting-ledger bridge "
+                         "bytes, the read path's and loader's spans) "
+                         "plus the full accounting-ledger bridge "
                          "through the cluster's MetricsCollector to this "
                          "JSONL sink (periodic ticks + a final explicit "
                          "flush)")
@@ -207,7 +208,9 @@ def run(args: argparse.Namespace,
     # collector to a JSONL sink (periodic ticks in the loop below plus a
     # final explicit flush). Per-rank read bytes are recorded on each
     # issuing session, so the PER_RANK view ties each loader's traffic
-    # to its (node, worker) coordinate.
+    # to its (node, worker) coordinate. The program's spans (read path,
+    # loader) are recorded too and folded in: per span name the P50/P99
+    # duration and each counter's sum.
     sink = (JsonlSink(args.metrics_jsonl,
                       every_s=args.metrics_every or None)
             if args.metrics_jsonl else None)
@@ -288,6 +291,9 @@ def run(args: argparse.Namespace,
     losses: List[float] = []
     step_s: List[float] = []
     n_done = start_step
+    spans_forced = SPANS.forced
+    if sink is not None:
+        SPANS.forced = True
     try:
         t_compile = time.perf_counter()
         step_fn = jax.jit(make_train_step(model, ocfg,
@@ -311,6 +317,7 @@ def run(args: argparse.Namespace,
                                  reduce=Reduce.P99)
                 cm.record_metric("train.items", args.global_batch,
                                  rate=True)
+                fold_spans(cm, SPANS.drain())
                 sink.tick(cm)
             if n_done % 10 == 0 or n_done == args.steps:
                 dt = time.perf_counter() - t0
@@ -333,6 +340,7 @@ def run(args: argparse.Namespace,
         if args.ckpt_fanstore and n_done % args.ckpt_every != 0:
             save_to_session(sessions[order[0]], n_done, state, extra=extra)
     finally:
+        SPANS.forced = spans_forced
         try:
             loader.close()   # may re-raise an in-flight window error
         finally:
@@ -342,6 +350,7 @@ def run(args: argparse.Namespace,
     if sink is not None:
         # final explicit flush: the last snapshot carries the complete
         # ledger bridge (the clocks outlive cluster.close())
+        fold_spans(cluster.metrics, SPANS.drain())
         snap = sink.flush(cluster.metrics)
         sink.close()
         view = sessions[order[0]].metrics()
