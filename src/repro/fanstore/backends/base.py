@@ -2,13 +2,13 @@
 
 Every byte in the cluster crosses a :class:`TransportBackend`. The base
 class owns everything the backends must agree on — the verb surface the
-engine calls (``fetch_local`` / ``fetch_remote`` / ``fetch_remote_batch``
-/ ``fetch_window`` / ``prefetch_local`` / ``put_local`` /
-``put_remote_batch``), the *modeled* cost accounting those verbs accrue
-onto the per-node ``NodeClock`` timelines (identical for every backend,
-so modeled quantities never depend on which wire moved the bytes), the
-shared thread pool behind the async ``submit`` API, and the lifecycle
-(``start``/``close``, context manager).
+engine calls (``fetch_local`` / ``fetch_local_many`` / ``fetch_remote`` /
+``fetch_remote_batch`` / ``fetch_window`` / ``prefetch_local`` /
+``put_local`` / ``put_remote_batch``), the *modeled* cost accounting
+those verbs accrue onto the per-node ``NodeClock`` timelines (identical
+for every backend, so modeled quantities never depend on which wire
+moved the bytes), the shared thread pool behind the async ``submit``
+API, and the lifecycle (``start``/``close``, context manager).
 
 Subclasses override only the two payload-movement primitives:
 
@@ -61,6 +61,11 @@ class TransportBackend:
     name = "base"
     #: True when the backend performs real transfers worth wall-clock timing
     measured = False
+    #: True when :meth:`_move_fetch` serves a request with one
+    #: ``NodeStore.serve_many`` pass, which copies raw input records
+    #: straight from the owner's partition blobs (``read_many`` counts
+    #: such files in its ``files_gathered``)
+    gathers = False
 
     def __init__(self, net, nodes: Dict[int, NodeStore],
                  clocks: Dict[int, NodeClock], *,
@@ -261,28 +266,40 @@ class TransportBackend:
         lane (attributed to ``tenant``) instead of ``consume_s`` — a
         serving tenant's local read must not serialize into the trainer's
         demand timeline."""
-        node = self.nodes[node_id]
+        return self.fetch_local_many(node_id, [item], materialize=materialize,
+                                     lane=lane, tenant=tenant)[0]
+
+    def fetch_local_many(self, node_id: int, items: Sequence[FetchItem], *,
+                         materialize: bool = True, lane: str = "consume",
+                         tenant: Optional[str] = None) -> List[bytes]:
+        """Read many files the requesting node holds, in one store pass
+        (``NodeStore.gather``). The ledgers come out as a
+        :meth:`fetch_local` of each, in order, would leave them: each
+        file's ``local_cost`` accrues on the requester's clock in item
+        order (float sums depend on it), under one lock acquisition; a
+        measured wire books one request and the file's bytes per file."""
         if materialize:
             t0 = time.perf_counter_ns() if self.measured else 0
-            data = node.open_local(item.path)
-            node.release(item.path)
+            out = self.nodes[node_id].gather([it.path for it in items])
             if self.measured:
                 self._wall_accrue(node_id, lane,
                                   time.perf_counter_ns() - t0,
-                                  bytes_in=len(data), requests=1)
+                                  bytes_in=sum(map(len, out)),
+                                  requests=len(items))
         else:
-            data = b""
+            out = [b""] * len(items)
+        local_cost = self.net.local_cost
         with self._lock:
             clock = self.clocks[node_id]
-            cost = self.net.local_cost(item.size,
-                                       compressed=item.compressed)
-            if lane == "serve_app":
-                clock.attribute_tenant(tenant or "anon", nbytes=item.size,
-                                       cost_s=cost, requests=1)
-            else:
-                clock.consume_s += cost
-            clock.local_bytes += item.size
-        return data
+            for it in items:
+                cost = local_cost(it.size, compressed=it.compressed)
+                if lane == "serve_app":
+                    clock.attribute_tenant(tenant or "anon", nbytes=it.size,
+                                           cost_s=cost, requests=1)
+                else:
+                    clock.consume_s += cost
+                clock.local_bytes += it.size
+        return out
 
     # ---- remote tier -------------------------------------------------------
     def fetch_remote(self, requester: int, owner: int, item: FetchItem, *,
@@ -376,18 +393,12 @@ class TransportBackend:
         """Stage node-local files (SSD tier) into the client cache ahead of
         demand; costs accrue on the prefetch lane so the disk reads overlap
         the consume timeline."""
-        node = self.nodes[node_id]
-        out: List[bytes] = []
+        t0 = time.perf_counter_ns() if self.measured else 0
+        out = self.nodes[node_id].gather([it.path for it in items]) \
+            if materialize else [b""] * len(items)
         total = 0
         cost = 0.0
-        t0 = time.perf_counter_ns() if self.measured else 0
         for it in items:
-            if materialize:
-                data = node.open_local(it.path)
-                node.release(it.path)
-            else:
-                data = b""
-            out.append(data)
             total += it.size
             cost += self.net.local_cost(it.size, compressed=it.compressed)
         if self.measured and materialize:

@@ -9,11 +9,13 @@ for compatibility).
 
 ``ModeledBackend`` moves payloads by direct in-process calls against the
 owner's ``NodeStore`` — exactly what the pre-seam ``Transport`` did, and
-regression-pinned to stay byte-for-byte identical: the movement is the
-same ``serve_remote``/``stage_output`` call sequence, and the modeled
-clock accrual lives unchanged in :class:`TransportBackend`. It records no
-measured wall time (``measured = False``): predictions stay the modeled
-clocks' job, hardware truth is the socket/shm backends' job.
+regression-pinned to stay byte-for-byte identical: an owner serves each
+request with one ``NodeStore.serve_many`` pass (the same bytes and store
+stats as a ``serve_remote`` per file), outputs ship through
+``stage_output``, and the modeled clock accrual lives unchanged in
+:class:`TransportBackend`. It records no measured wall time
+(``measured = False``): predictions stay the modeled clocks' job,
+hardware truth is the socket/shm backends' job.
 """
 from __future__ import annotations
 
@@ -66,12 +68,13 @@ class ModeledBackend(TransportBackend):
 
     name = "modeled"
     measured = False
+    gathers = True
 
     def _move_fetch(self, requester: int, owner: int,
                     items: Sequence[FetchItem], materialize: bool,
                     verb: str) -> Tuple[List[bytes], int]:
         if materialize:
-            out = [self.nodes[owner].serve_remote(it.path) for it in items]
+            out = self.nodes[owner].serve_many([it.path for it in items])
         else:
             out = [b"" for _ in items]
         return out, 0

@@ -460,14 +460,14 @@ class FanStoreCluster:
     # ---- reads -------------------------------------------------------------
     def _fetch_item(self, path: str, st: StatRecord,
                     loc: FileLocation) -> FetchItem:
-        """Resolve the sizes the transport cost model needs for one file."""
-        rec = None
-        if self.nodes[loc.node_id].has(path):
-            rec = self.nodes[loc.node_id].record_for(path)
-        compressed = bool(rec and rec.compressed_size)
-        return FetchItem(path=path, size=st.st_size,
-                         stored=rec.stored_size if rec else st.st_size,
-                         compressed=compressed)
+        """Resolve the sizes the transport cost model needs for one file:
+        the primary owner's read record, resolved when it indexed the
+        partition, or the stat's size for a file it holds no record of
+        (a committed output)."""
+        rec = self.nodes[loc.node_id].read_record(path)
+        if rec is not None:
+            return rec.item
+        return FetchItem(path=path, size=st.st_size, stored=st.st_size)
 
     def _lookup(self, path: str) -> Tuple[StatRecord, FileLocation]:
         """Resolve a path against the replicated input metadata, falling
@@ -502,9 +502,14 @@ class FanStoreCluster:
             owners = [o for o in owners if o != avoid]
         if not owners:
             return None
-        load = {o: self.clocks[o].serve_s + pending_serve.get(o, 0.0)
-                for o in owners}
-        owner = self.selector.choose(owners, load)
+        if len(owners) == 1:
+            owner = owners[0]
+        else:
+            load = {o: self.clocks[o].serve_s + pending_serve.get(o, 0.0)
+                    for o in owners}
+            owner = self.selector.choose(owners, load)
+        # booked for a single owner too, so a later file of the batch with
+        # several owners sees the same load
         pending_serve[owner] = pending_serve.get(owner, 0.0) + (
             self.net.local_cost(item.stored)
             + item.stored / self.net.bandwidth_Bps)
@@ -541,7 +546,8 @@ class FanStoreCluster:
             int, List[Tuple[int, FetchItem, FileLocation]]], *,
             materialize: bool, batched: bool, window: bool,
             on_data, lost_ok: bool, lane: str = "consume",
-            tenant: Optional[str] = None) -> Tuple[int, int]:
+            tenant: Optional[str] = None,
+            out: Optional[List] = None) -> Tuple[int, int]:
         """Drain an (owner -> [(slot, item, loc)]) worklist, classifying
         owner errors and retrying on the next live replica. Returns the
         round trips that succeeded and the retries paid.
@@ -558,7 +564,8 @@ class FanStoreCluster:
         best-effort prefetch path; demand reads surface the loss).
         Non-transport errors re-raise unclassified: a genuine
         ``FileNotFoundError`` must never burn replicas. Successful
-        payloads are delivered through ``on_data(slot, item, data)``.
+        payloads are delivered through ``on_data(slot, item, data)``, or
+        stored as ``out[slot]`` where ``out`` is given.
 
         Termination: every retry either removes a group (success), or
         strikes its owner — and at ``fault_threshold`` strikes the owner
@@ -599,6 +606,10 @@ class FanStoreCluster:
                 self._note_owner_ok(owner)
                 trips += 1 if window or batched else len(items)
                 del groups[owner]
+                if out is not None:
+                    for (slot, _, _), data in zip(entries, datas):
+                        out[slot] = data
+                    continue
                 for (slot, item, _), data in zip(entries, datas):
                     on_data(slot, item, data)
             if not failed:
@@ -674,6 +685,15 @@ class FanStoreCluster:
         from repro.fanstore.faults import NodeLostError
         out: List[Optional[bytes]] = [None] * len(paths)
         tier = self.cache_tiers[requester]
+        cached = tier.enabled
+        node = self.nodes[requester]
+        # the one-pass gather: with no cache tier to consult or fill, a
+        # batched, materialized read takes its local files in one store
+        # pass after the plan (their costs accrue in path order, as the
+        # per-file reads would) and stores remote payloads straight into
+        # ``out``
+        gather = batched and materialize and not cached
+        local: List[Tuple[int, FetchItem]] = []
         # (owner -> [(output slot, item, location)]) for the remote leg;
         # the location rides along so a failed fetch can re-route to the
         # next live replica without a second metadata pass
@@ -686,66 +706,93 @@ class FanStoreCluster:
         timed = bool(span)
         hits = files_local = bytes_local = local_ns = 0
         with span:
-            for i, raw in enumerate(paths):
-                path = raw.strip("/")
-                st, loc = self._lookup(path)
-                item = self._fetch_item(path, st, loc)
-                if tier.enabled:
-                    entry = tier.get(path, worker_id=worker_id,
-                                     require_data=materialize, job=job)
-                    if entry is not None:
-                        self.transport.account_cache_hit(
-                            requester, item, worker_id=worker_id,
-                            lane=lane, tenant=tenant, job=job)
-                        out[i] = entry.data if materialize else b""
-                        hits += 1
+            try:
+                for i, raw in enumerate(paths):
+                    path = raw.strip("/")
+                    st, loc = self._lookup(path)
+                    item = self._fetch_item(path, st, loc)
+                    if cached:
+                        entry = tier.get(path, worker_id=worker_id,
+                                         require_data=materialize, job=job)
+                        if entry is not None:
+                            self.transport.account_cache_hit(
+                                requester, item, worker_id=worker_id,
+                                lane=lane, tenant=tenant, job=job)
+                            out[i] = entry.data if materialize else b""
+                            hits += 1
+                            continue
+                        self.transport.account_cache_miss(
+                            requester, worker_id=worker_id, job=job)
+                    if node.has(path) or node.has_output(path):
+                        if gather:
+                            local.append((i, item))
+                            continue
+                        if timed:
+                            t0 = time.perf_counter_ns()
+                        data = self.transport.fetch_local(
+                            requester, item, materialize=materialize,
+                            lane=lane, tenant=tenant)
+                        if timed:
+                            local_ns += time.perf_counter_ns() - t0
+                            files_local += 1
+                            bytes_local += item.size
+                        out[i] = data
+                        if cached:
+                            ev = tier.put(path, data if materialize else None,
+                                          size=item.size,
+                                          worker_id=worker_id, job=job)
+                            self.transport.account_cache_eviction(requester,
+                                                                  ev)
                         continue
-                    self.transport.account_cache_miss(requester,
-                                                      worker_id=worker_id,
-                                                      job=job)
-                if self.nodes[requester].has(path) or \
-                        self.nodes[requester].has_output(path):
+                    owner = self._choose_owner(loc, item, pending_serve)
+                    if owner is None:
+                        raise NodeLostError.for_items(
+                            [(path, loc.partition_id)])
+                    groups.setdefault(owner, []).append((i, item, loc))
+            finally:
+                # the gather's local leg runs even where the plan raised:
+                # the per-file reads had taken every local file before the
+                # one that failed
+                if local:
                     if timed:
                         t0 = time.perf_counter_ns()
-                    data = self.transport.fetch_local(
-                        requester, item, materialize=materialize,
-                        lane=lane, tenant=tenant)
+                    datas = self.transport.fetch_local_many(
+                        requester, [it for _, it in local], lane=lane,
+                        tenant=tenant)
                     if timed:
                         local_ns += time.perf_counter_ns() - t0
-                        files_local += 1
-                        bytes_local += item.size
-                    out[i] = data
-                    if tier.enabled:
-                        ev = tier.put(path, data if materialize else None,
-                                      size=item.size, worker_id=worker_id,
-                                      job=job)
-                        self.transport.account_cache_eviction(requester, ev)
-                    continue
-                owner = self._choose_owner(loc, item, pending_serve)
-                if owner is None:
-                    raise NodeLostError.for_items([(path, loc.partition_id)])
-                groups.setdefault(owner, []).append((i, item, loc))
+                        files_local += len(local)
+                        bytes_local += sum(it.size for _, it in local)
+                    for (slot, _), data in zip(local, datas):
+                        out[slot] = data
 
             def deliver(slot: int, item: FetchItem, data: bytes) -> None:
                 out[slot] = data
-                if tier.enabled:
-                    ev = tier.put(item.path, data if materialize else None,
-                                  size=item.size, worker_id=worker_id,
-                                  job=job)
-                    self.transport.account_cache_eviction(requester, ev)
+                ev = tier.put(item.path, data if materialize else None,
+                              size=item.size, worker_id=worker_id, job=job)
+                self.transport.account_cache_eviction(requester, ev)
 
             if timed:
+                gathered = 0
+                if gather:
+                    gathered = node.count_raw(it.path for _, it in local)
+                    if self.transport.gathers:
+                        gathered += sum(
+                            self.nodes[o].count_raw(it.path for _, it, _ in e)
+                            for o, e in groups.items())
                 span.counters.update(
                     files_local=files_local, bytes_local=bytes_local,
                     local_ns=local_ns, cache_hits=hits,
                     files_remote=sum(map(len, groups.values())),
                     bytes_remote=sum(it.stored for entries in groups.values()
-                                     for _, it, _ in entries))
+                                     for _, it, _ in entries),
+                    files_gathered=gathered)
             with SPANS.span("fanstore.read_many.remote"):
                 trips, retries = self._fetch_with_failover(
                     requester, groups, materialize=materialize,
                     batched=batched, window=False, on_data=deliver,
-                    lost_ok=False, lane=lane, tenant=tenant)
+                    lost_ok=False, lane=lane, tenant=tenant,
+                    out=None if cached else out)
             if timed:
                 span.counters.update(owners=trips, retries=retries)
         return out  # type: ignore[return-value]

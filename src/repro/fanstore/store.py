@@ -3,7 +3,9 @@
 Each compute node runs one ``NodeStore`` holding:
   * the partitions assigned to it ("local SSD" tier — kept in RAM here, with
     an optional spill directory to model the on-disk layout),
-  * an index path -> (partition_id, record) for its local files,
+  * an index path -> (partition_id, record) for its local files, and a
+    read record per file resolved when its partition is indexed (the
+    cost model's sizes; for a payload stored raw, its bounds in the blob),
   * the refcount file cache: a file's decompressed bytes stay cached while any
     open descriptor refers to it and are evicted when the count reaches zero
     (paper: uniform random access defeats LRU; evict-on-last-close instead),
@@ -21,16 +23,29 @@ from __future__ import annotations
 import os
 import threading
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, Iterable, List, NamedTuple, Optional, Sequence, Tuple
 
 from repro.fanstore.layout import FileRecord, iter_partition
 from repro.fanstore.metadata import StatRecord
+from repro.fanstore.wire import FetchItem
 
 
 @dataclass
 class _CacheEntry:
     data: bytes
     refcount: int = 0
+
+
+class ReadRecord(NamedTuple):
+    """What a read of one input file needs, resolved once when its
+    partition is indexed (an input is immutable while it is held, paper
+    §3.5): the cost model's sizes, and for a payload stored raw the blob
+    and the payload's bounds in it (``blob`` None where it must
+    decompress)."""
+    item: FetchItem
+    blob: Optional[bytes]
+    start: int
+    stop: int
 
 
 @dataclass
@@ -66,6 +81,7 @@ class NodeStore:
         self.spill_dir = spill_dir
         self._partitions: Dict[int, bytes] = {}
         self._index: Dict[str, Tuple[int, FileRecord]] = {}
+        self._reads: Dict[str, ReadRecord] = {}
         self._cache: Dict[str, _CacheEntry] = {}
         # the refcount cache is mutated by every thread that serves this
         # node — transport pool workers AND (socket backend) per-connection
@@ -95,6 +111,12 @@ class NodeStore:
         paths = []
         for rec in iter_partition(blob, codec=self.codec):
             self._index[rec.path] = (partition_id, rec)
+            stop = rec.data_offset + rec.stored_size
+            self._reads[rec.path] = ReadRecord(
+                FetchItem(path=rec.path, size=rec.stat.st_size,
+                          stored=rec.stored_size,
+                          compressed=bool(rec.compressed_size)),
+                None if rec.compressed_size else blob, rec.data_offset, stop)
             paths.append(rec.path)
         return paths
 
@@ -102,6 +124,7 @@ class NodeStore:
         self._partitions.pop(partition_id, None)
         self._index = {p: (pid, r) for p, (pid, r) in self._index.items()
                        if pid != partition_id}
+        self._reads = {p: self._reads[p] for p in self._index}
 
     @property
     def partition_ids(self) -> Tuple[int, ...]:
@@ -112,6 +135,17 @@ class NodeStore:
 
     def local_paths(self) -> List[str]:
         return list(self._index)
+
+    def read_record(self, path: str) -> Optional[ReadRecord]:
+        return self._reads.get(path)
+
+    def count_raw(self, paths: Iterable[str]) -> int:
+        """How many of ``paths`` this store holds as input records stored
+        raw: the files :meth:`gather` copies straight from a blob."""
+        reads = self._reads
+        return sum(1 for p in paths
+                   if (rec := reads.get(p)) is not None
+                   and rec.blob is not None)
 
     def record_for(self, path: str) -> Optional[FileRecord]:
         hit = self._index.get(path)
@@ -137,43 +171,76 @@ class NodeStore:
         already, so they bypass the refcount cache.
         """
         with self._cache_lock:
-            entry = self._cache.get(path)
-            if entry is not None:
-                entry.refcount += 1
-                self.stats["cache_hits"] += 1
-                return entry.data
-            hit = self._index.get(path)
-            if hit is None:
-                out = self._outputs.get(path)
-                if out is not None:
-                    self.stats["local_opens"] += 1
-                    self.stats["bytes_read"] += len(out)
-                    return out
-                raise FileNotFoundError(path)
-            pid, rec = hit
-            blob = self._partitions[pid]
-            raw = blob[rec.data_offset: rec.data_offset + rec.stored_size]
-            if rec.compressed_size:
-                from repro.fanstore.layout import _decompress
-                data = _decompress(self.codec, bytes(raw), rec.stat.st_size)
-                self.stats["decompressed"] += 1
-            else:
-                data = bytes(raw)
-            self._cache[path] = _CacheEntry(data=data, refcount=1)
-            self.stats["local_opens"] += 1
-            self.stats["bytes_read"] += len(data)
-            return data
+            return self._open_locked(path)
+
+    def _open_locked(self, path: str) -> bytes:
+        entry = self._cache.get(path)
+        if entry is not None:
+            entry.refcount += 1
+            self.stats["cache_hits"] += 1
+            return entry.data
+        hit = self._index.get(path)
+        if hit is None:
+            out = self._outputs.get(path)
+            if out is not None:
+                self.stats["local_opens"] += 1
+                self.stats["bytes_read"] += len(out)
+                return out
+            raise FileNotFoundError(path)
+        pid, rec = hit
+        blob = self._partitions[pid]
+        raw = blob[rec.data_offset: rec.data_offset + rec.stored_size]
+        if rec.compressed_size:
+            from repro.fanstore.layout import _decompress
+            data = _decompress(self.codec, bytes(raw), rec.stat.st_size)
+            self.stats["decompressed"] += 1
+        else:
+            data = bytes(raw)
+        self._cache[path] = _CacheEntry(data=data, refcount=1)
+        self.stats["local_opens"] += 1
+        self.stats["bytes_read"] += len(data)
+        return data
 
     def release(self, path: str) -> None:
         """close(): refcount--; evict at zero (paper's counter table)."""
         with self._cache_lock:
-            entry = self._cache.get(path)
-            if entry is None:
-                return
-            entry.refcount -= 1
-            if entry.refcount <= 0:
-                del self._cache[path]
-                self.stats["evictions"] += 1
+            self._release_locked(path)
+
+    def _release_locked(self, path: str) -> None:
+        entry = self._cache.get(path)
+        if entry is None:
+            return
+        entry.refcount -= 1
+        if entry.refcount <= 0:
+            del self._cache[path]
+            self.stats["evictions"] += 1
+
+    def gather(self, paths: Sequence[str]) -> List[bytes]:
+        """Read many files in one pass, as an ``open_local`` and a
+        ``release`` of each would: the same bytes and the same
+        ``stats``. An input record stored raw that no descriptor holds
+        open is copied straight from its partition blob, without the
+        insert into and delete from the refcount cache that the pair
+        makes; every other file takes that pair."""
+        reads, cache = self._reads, self._cache
+        out: List[bytes] = []
+        sliced = nbytes = 0
+        with self._cache_lock:
+            for path in paths:
+                rec = reads.get(path)
+                if rec is None or rec.blob is None or path in cache:
+                    out.append(self._open_locked(path))
+                    self._release_locked(path)
+                    continue
+                data = bytes(rec.blob[rec.start:rec.stop])
+                out.append(data)
+                sliced += 1
+                nbytes += len(data)
+            stats = self.stats
+            stats["local_opens"] += sliced
+            stats["bytes_read"] += nbytes
+            stats["evictions"] += sliced
+        return out
 
     def serve_remote(self, path: str) -> bytes:
         """Handle a peer's round-trip read request (no cache interaction)."""
@@ -182,6 +249,13 @@ class NodeStore:
         self.release(path)
         self.stats["bytes_served"] += len(data)
         return data
+
+    def serve_many(self, paths: Sequence[str]) -> List[bytes]:
+        """A peer's batched read request: :meth:`gather`, booked as
+        served (the same ``stats`` as a ``serve_remote`` of each)."""
+        out = self.gather(paths)
+        self.stats["bytes_served"] += sum(map(len, out))
+        return out
 
     def serve_remote_view(self, path: str) -> memoryview:
         """Zero-copy serve for co-located requesters (the shared-memory

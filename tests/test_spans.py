@@ -424,6 +424,9 @@ def test_train_metrics_jsonl_carries_the_span_folds(tmp_path):
     assert read["count"] >= 3 and read["p50"] > 0 and read["p99"] > 0
     assert m["fanstore.read_many.files_local"]["value"] + \
         m["fanstore.read_many.files_remote"]["value"] == 4 * read["count"]
+    # a raw dataset, no cache tier, the modeled wire: the gather serves all
+    assert m["fanstore.read_many.files_gathered"]["value"] == \
+        4 * read["count"]
     for name in ("fanstore.loader.fetch", "fanstore.loader.decode",
                  "fanstore.loader.put_wait", "fanstore.loader.get"):
         assert m[f"{name}.ms"]["count"] >= 3, name
