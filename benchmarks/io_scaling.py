@@ -818,19 +818,22 @@ def run_checkpoint_overlap(nodes: int, file_size: int, count: int,
 
     # overlapped: both workloads on one set of clocks, concurrent lanes
     cluster, traces = build()
-    cluster.reset_clocks()
-    _drive_prefetched_epoch(cluster, traces, window=window)
-    write_shards(cluster)
-    overlapped = cluster.makespan_s()
+    with cluster:
+        cluster.reset_clocks()
+        _drive_prefetched_epoch(cluster, traces, window=window)
+        write_shards(cluster)
+        overlapped = cluster.makespan_s()
     # serialized: prefetch epoch alone + write alone, summed
     cluster, traces = build()
-    cluster.reset_clocks()
-    _drive_prefetched_epoch(cluster, traces, window=window)
-    prefetch_only = cluster.makespan_s()
+    with cluster:
+        cluster.reset_clocks()
+        _drive_prefetched_epoch(cluster, traces, window=window)
+        prefetch_only = cluster.makespan_s()
     cluster2, _ = build()
-    cluster2.reset_clocks()
-    write_shards(cluster2)
-    write_only = cluster2.makespan_s()
+    with cluster2:
+        cluster2.reset_clocks()
+        write_shards(cluster2)
+        write_only = cluster2.makespan_s()
     serialized = prefetch_only + write_only
     return {"nodes": nodes, "shard_bytes": shard_bytes,
             "overlapped_makespan_s": overlapped,

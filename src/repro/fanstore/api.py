@@ -398,7 +398,14 @@ class FanStoreSession:
         """Batched whole-file reads: one modeled round trip per (this node,
         owner) pair instead of one per file. A serving session
         (``read_lane="serve_app"``) books the cost onto the concurrent
-        serving timeline, attributed to its tenant."""
+        serving timeline, attributed to its tenant.
+
+        Returns read-only bytes-like objects: ``bytes``, or a
+        ``memoryview`` over storage that FanStore never mutates, valid for
+        as long as the caller holds it (see
+        :meth:`FanStoreCluster.read_many` for which reads give views). The
+        descriptor verbs (``open``/``read``/``pread``) always give
+        ``bytes``."""
         return self.cluster.read_many(
             self.node_id, [self.resolve(p) for p in paths],
             worker_id=self.worker_id, materialize=materialize,

@@ -63,8 +63,9 @@ class TransportBackend:
     measured = False
     #: True when :meth:`_move_fetch` serves a request with one
     #: ``NodeStore.serve_many`` pass, which copies raw input records
-    #: straight from the owner's partition blobs (``read_many`` counts
-    #: such files in its ``files_gathered``)
+    #: straight from the owner's partition blobs, or hands them out as
+    #: views where the caller takes them (``read_many`` counts such files
+    #: in its ``files_gathered``)
     gathers = False
 
     def __init__(self, net, nodes: Dict[int, NodeStore],
@@ -226,13 +227,16 @@ class TransportBackend:
     # ---- movement primitives (the only parts a wire must provide) ----------
     def _move_fetch(self, requester: int, owner: int,
                     items: Sequence[FetchItem], materialize: bool,
-                    verb: str) -> Tuple[List[bytes], int]:
+                    verb: str, views: bool = False
+                    ) -> Tuple[List[bytes], int]:
         """Move ``items``'s payloads from ``owner`` to ``requester``.
 
         ``verb`` is ``"fetch"`` / ``"fetch_batch"`` / ``"fetch_window"`` so
-        a framed wire can keep the transport's intent visible. Returns
-        (payloads in item order, server-side handling nanoseconds — 0 when
-        the wire cannot observe it)."""
+        a framed wire can keep the transport's intent visible. ``views``
+        says the caller takes read-only views of the owner's blobs
+        (``NodeStore.gather``); a wire that copies on receive ignores it.
+        Returns (payloads in item order, server-side handling nanoseconds
+        — 0 when the wire cannot observe it)."""
         raise NotImplementedError
 
     def _move_put(self, writer: int, owner: int,
@@ -271,16 +275,18 @@ class TransportBackend:
 
     def fetch_local_many(self, node_id: int, items: Sequence[FetchItem], *,
                          materialize: bool = True, lane: str = "consume",
-                         tenant: Optional[str] = None) -> List[bytes]:
+                         tenant: Optional[str] = None,
+                         views: bool = False) -> List[bytes]:
         """Read many files the requesting node holds, in one store pass
-        (``NodeStore.gather``). The ledgers come out as a
-        :meth:`fetch_local` of each, in order, would leave them: each
+        (``NodeStore.gather``, ``views`` as there). The ledgers come out
+        as a :meth:`fetch_local` of each, in order, would leave them: each
         file's ``local_cost`` accrues on the requester's clock in item
         order (float sums depend on it), under one lock acquisition; a
         measured wire books one request and the file's bytes per file."""
         if materialize:
             t0 = time.perf_counter_ns() if self.measured else 0
-            out = self.nodes[node_id].gather([it.path for it in items])
+            out = self.nodes[node_id].gather([it.path for it in items],
+                                             views=views)
             if self.measured:
                 self._wall_accrue(node_id, lane,
                                   time.perf_counter_ns() - t0,
@@ -316,7 +322,8 @@ class TransportBackend:
     def fetch_remote_batch(self, requester: int, owner: int,
                            items: Sequence[FetchItem], *,
                            materialize: bool = True, lane: str = "consume",
-                           tenant: Optional[str] = None) -> List[bytes]:
+                           tenant: Optional[str] = None,
+                           views: bool = False) -> List[bytes]:
         """Coalesced fetch: K files from one owner, ONE round-trip latency.
 
         The requester pays ``latency_s`` once for the whole group and the
@@ -325,12 +332,15 @@ class TransportBackend:
         costs are unchanged. See ``_account_remote`` for the exact model.
         ``lane="serve_app"`` routes the requester-side cost onto the
         concurrent serving lane with per-``tenant`` attribution.
+        ``views``: the caller takes read-only views of the owner's blobs
+        where the wire serves by ``NodeStore.serve_many`` (see
+        :meth:`_move_fetch`).
         """
         if not items:
             return []
         with SPANS.span("fanstore.fetch.remote") as span:
             out = self._timed_fetch(requester, owner, items, materialize,
-                                    "fetch_batch", lane)
+                                    "fetch_batch", lane, views)
             if span:
                 t0 = time.perf_counter_ns()
             with self._lock:
@@ -372,16 +382,17 @@ class TransportBackend:
 
     def _timed_fetch(self, requester: int, owner: int,
                      items: Sequence[FetchItem], materialize: bool,
-                     verb: str, lane: str) -> List[bytes]:
+                     verb: str, lane: str,
+                     views: bool = False) -> List[bytes]:
         """Run the movement primitive, wall-timing it on measured wires."""
         self._maybe_inject(requester, owner, verb)
         if not self.measured:
             out, _ = self._move_fetch(requester, owner, items, materialize,
-                                      verb)
+                                      verb, views)
             return out
         t0 = time.perf_counter_ns()
         out, serve_ns = self._move_fetch(requester, owner, items,
-                                         materialize, verb)
+                                         materialize, verb, views)
         moved = sum(len(d) for d in out)
         self._wall_accrue(requester, lane, time.perf_counter_ns() - t0,
                           bytes_in=moved, requests=1, owner=owner,

@@ -11,7 +11,10 @@ for compatibility).
 owner's ``NodeStore`` — exactly what the pre-seam ``Transport`` did, and
 regression-pinned to stay byte-for-byte identical: an owner serves each
 request with one ``NodeStore.serve_many`` pass (the same bytes and store
-stats as a ``serve_remote`` per file), outputs ship through
+stats as a ``serve_remote`` per file; a caller that takes views gets raw
+records as views over the owner's blobs, since both sides share one
+address space and the wire's cost is booked on the clocks, not paid in
+wall time), outputs ship through
 ``stage_output``, and the modeled clock accrual lives unchanged in
 :class:`TransportBackend`. It records no measured wall time
 (``measured = False``): predictions stay the modeled clocks' job,
@@ -72,9 +75,11 @@ class ModeledBackend(TransportBackend):
 
     def _move_fetch(self, requester: int, owner: int,
                     items: Sequence[FetchItem], materialize: bool,
-                    verb: str) -> Tuple[List[bytes], int]:
+                    verb: str, views: bool = False
+                    ) -> Tuple[List[bytes], int]:
         if materialize:
-            out = self.nodes[owner].serve_many([it.path for it in items])
+            out = self.nodes[owner].serve_many([it.path for it in items],
+                                               views=views)
         else:
             out = [b"" for _ in items]
         return out, 0

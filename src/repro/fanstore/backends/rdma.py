@@ -227,7 +227,8 @@ class RdmaBackend(TransportBackend):
 
     def _move_fetch(self, requester: int, owner: int,
                     items: Sequence[FetchItem], materialize: bool,
-                    verb: str) -> Tuple[List[bytes], int]:
+                    verb: str, views: bool = False
+                    ) -> Tuple[List[bytes], int]:
         if not materialize:
             return [b"" for _ in items], 0
         store = self.nodes[owner]

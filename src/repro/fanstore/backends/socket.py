@@ -468,7 +468,8 @@ class SocketBackend(TransportBackend):
     # ---- movement primitives -----------------------------------------------
     def _move_fetch(self, requester: int, owner: int,
                     items: Sequence[FetchItem], materialize: bool,
-                    verb: str) -> Tuple[List[bytes], int]:
+                    verb: str, views: bool = False
+                    ) -> Tuple[List[bytes], int]:
         pool = self._stripe_pool
         n_stripes = min(self.stripes, len(items)) if materialize else 1
         if (n_stripes > 1 and pool is not None

@@ -547,7 +547,8 @@ class FanStoreCluster:
             materialize: bool, batched: bool, window: bool,
             on_data, lost_ok: bool, lane: str = "consume",
             tenant: Optional[str] = None,
-            out: Optional[List] = None) -> Tuple[int, int]:
+            out: Optional[List] = None,
+            views: bool = False) -> Tuple[int, int]:
         """Drain an (owner -> [(slot, item, loc)]) worklist, classifying
         owner errors and retrying on the next live replica. Returns the
         round trips that succeeded and the retries paid.
@@ -565,7 +566,8 @@ class FanStoreCluster:
         Non-transport errors re-raise unclassified: a genuine
         ``FileNotFoundError`` must never burn replicas. Successful
         payloads are delivered through ``on_data(slot, item, data)``, or
-        stored as ``out[slot]`` where ``out`` is given.
+        stored as ``out[slot]`` where ``out`` is given. ``views`` is
+        passed to each batched fetch (``fetch_remote_batch``).
 
         Termination: every retry either removes a group (success), or
         strikes its owner — and at ``fault_threshold`` strikes the owner
@@ -591,7 +593,7 @@ class FanStoreCluster:
                     elif batched:
                         datas = self.transport.fetch_remote_batch(
                             requester, owner, items, materialize=materialize,
-                            lane=lane, tenant=tenant)
+                            lane=lane, tenant=tenant, views=views)
                     else:
                         datas = [self.transport.fetch_remote(
                             requester, owner, it, materialize=materialize,
@@ -648,6 +650,7 @@ class FanStoreCluster:
         accounting but skips the payload copies — used by the scaling
         benchmarks, where 512 nodes x thousands of multi-MB reads would
         spend their wall time in host memcpy instead of the modeled fabric.
+        Always owning ``bytes`` (``b""`` unmaterialized), never a view.
         """
         return self.read_many(requester, [path], worker_id=worker_id,
                               materialize=materialize, batched=False,
@@ -659,6 +662,20 @@ class FanStoreCluster:
                   tenant: Optional[str] = None,
                   job: Optional[str] = None) -> List[bytes]:
         """Batched read: all remote requests for one owner ride ONE round trip.
+
+        Returns read-only bytes-like objects, in input order: ``bytes``, or
+        a ``memoryview`` over storage that FanStore never mutates, valid
+        for as long as the caller holds it (the view keeps its partition
+        blob alive, past a ``drop_partition`` or a node's death). Views
+        come from the one-pass gather (below): an input record stored
+        raw, read from the requester's own partitions or, on a wire that
+        serves by ``NodeStore.serve_many`` (the in-process modeled wire),
+        from an owner's. Everything else is owning ``bytes``: LZSS
+        records, committed outputs, files a descriptor holds open, the
+        remote legs of wires that copy on receive, every read with the
+        cache tier on (no view is ever stored in a tier), and every read
+        with ``batched=False`` or ``materialize=False``. A caller that
+        needs ``bytes`` methods takes ``bytes(d)``.
 
         ``batched=False`` degrades to per-file round trips (the paper's
         synchronous client), byte-for-byte identical to the seed ``read``
@@ -691,7 +708,8 @@ class FanStoreCluster:
         # batched, materialized read takes its local files in one store
         # pass after the plan (their costs accrue in path order, as the
         # per-file reads would) and stores remote payloads straight into
-        # ``out``
+        # ``out``; raw records come back as views of their blobs, since
+        # nothing will store them
         gather = batched and materialize and not cached
         local: List[Tuple[int, FetchItem]] = []
         # (owner -> [(output slot, item, location)]) for the remote leg;
@@ -758,7 +776,7 @@ class FanStoreCluster:
                         t0 = time.perf_counter_ns()
                     datas = self.transport.fetch_local_many(
                         requester, [it for _, it in local], lane=lane,
-                        tenant=tenant)
+                        tenant=tenant, views=True)
                     if timed:
                         local_ns += time.perf_counter_ns() - t0
                         files_local += len(local)
@@ -792,9 +810,11 @@ class FanStoreCluster:
                     requester, groups, materialize=materialize,
                     batched=batched, window=False, on_data=deliver,
                     lost_ok=False, lane=lane, tenant=tenant,
-                    out=None if cached else out)
+                    out=None if cached else out, views=gather)
             if timed:
-                span.counters.update(owners=trips, retries=retries)
+                span.counters.update(
+                    owners=trips, retries=retries,
+                    files_viewed=list(map(type, out)).count(memoryview))
         return out  # type: ignore[return-value]
 
     def read_many_async(self, requester: int, paths: Sequence[str], *,

@@ -117,7 +117,9 @@ class FanStoreFS:
 
     def read_many(self, paths: List[str]) -> List[bytes]:
         """Batched whole-file reads through the engine: one modeled round
-        trip per (this node, owner) pair instead of one per file."""
+        trip per (this node, owner) pair instead of one per file. Read-only
+        bytes-like results, as :meth:`FanStoreSession.read_many` gives;
+        a file object's ``read`` gives ``bytes``."""
         return self.session.read_many([self.resolve(p) for p in paths])
 
     def stat(self, path: str) -> StatRecord:

@@ -39,11 +39,11 @@ class _CacheEntry:
 class ReadRecord(NamedTuple):
     """What a read of one input file needs, resolved once when its
     partition is indexed (an input is immutable while it is held, paper
-    §3.5): the cost model's sizes, and for a payload stored raw the blob
-    and the payload's bounds in it (``blob`` None where it must
-    decompress)."""
+    §3.5): the cost model's sizes, and for a payload stored raw a
+    read-only view of the whole blob (its ``obj`` the blob) and the
+    payload's bounds in it (``blob`` None where it must decompress)."""
     item: FetchItem
-    blob: Optional[bytes]
+    blob: Optional[memoryview]
     start: int
     stop: int
 
@@ -108,6 +108,7 @@ class NodeStore:
             with open(fn, "wb") as f:
                 f.write(blob)
         self._partitions[partition_id] = blob
+        view = memoryview(blob).toreadonly()
         paths = []
         for rec in iter_partition(blob, codec=self.codec):
             self._index[rec.path] = (partition_id, rec)
@@ -116,7 +117,7 @@ class NodeStore:
                 FetchItem(path=rec.path, size=rec.stat.st_size,
                           stored=rec.stored_size,
                           compressed=bool(rec.compressed_size)),
-                None if rec.compressed_size else blob, rec.data_offset, stop)
+                None if rec.compressed_size else view, rec.data_offset, stop)
             paths.append(rec.path)
         return paths
 
@@ -141,7 +142,7 @@ class NodeStore:
 
     def count_raw(self, paths: Iterable[str]) -> int:
         """How many of ``paths`` this store holds as input records stored
-        raw: the files :meth:`gather` copies straight from a blob."""
+        raw: the files :meth:`gather` serves straight from a blob."""
         reads = self._reads
         return sum(1 for p in paths
                    if (rec := reads.get(p)) is not None
@@ -215,13 +216,20 @@ class NodeStore:
             del self._cache[path]
             self.stats["evictions"] += 1
 
-    def gather(self, paths: Sequence[str]) -> List[bytes]:
+    def gather(self, paths: Sequence[str], *,
+               views: bool = False) -> List[bytes]:
         """Read many files in one pass, as an ``open_local`` and a
         ``release`` of each would: the same bytes and the same
         ``stats``. An input record stored raw that no descriptor holds
         open is copied straight from its partition blob, without the
         insert into and delete from the refcount cache that the pair
-        makes; every other file takes that pair."""
+        makes; every other file takes that pair.
+
+        With ``views`` such a record is not copied: it comes back as a
+        read-only ``memoryview`` over its partition blob (``obj`` the
+        blob). A blob is never mutated, and the view holds it alive, so
+        the view stays valid and equal to the file after the partition
+        is dropped. Every other file is still owning ``bytes``."""
         reads, cache = self._reads, self._cache
         out: List[bytes] = []
         sliced = nbytes = 0
@@ -232,7 +240,9 @@ class NodeStore:
                     out.append(self._open_locked(path))
                     self._release_locked(path)
                     continue
-                data = bytes(rec.blob[rec.start:rec.stop])
+                data = rec.blob[rec.start:rec.stop]
+                if not views:
+                    data = bytes(data)
                 out.append(data)
                 sliced += 1
                 nbytes += len(data)
@@ -250,10 +260,12 @@ class NodeStore:
         self.stats["bytes_served"] += len(data)
         return data
 
-    def serve_many(self, paths: Sequence[str]) -> List[bytes]:
-        """A peer's batched read request: :meth:`gather`, booked as
-        served (the same ``stats`` as a ``serve_remote`` of each)."""
-        out = self.gather(paths)
+    def serve_many(self, paths: Sequence[str], *,
+                   views: bool = False) -> List[bytes]:
+        """A peer's batched read request: :meth:`gather` (``views`` as
+        there), booked as served (the same ``stats`` as a
+        ``serve_remote`` of each)."""
+        out = self.gather(paths, views=views)
         self.stats["bytes_served"] += sum(map(len, out))
         return out
 

@@ -1,5 +1,8 @@
 """Layered I/O engine: batched transport accounting, client LRU cache,
 replica failover under read_many, async futures, FS commit regression."""
+import random
+
+import numpy as np
 import pytest
 
 from repro.data.pipeline import PrefetchLoader
@@ -192,22 +195,70 @@ def test_read_many_async_returns_future():
 
 
 def test_prefetch_loader_batched_path():
+    """A batched loader hands its decode what ``read_many`` returns:
+    read-only bytes-like payloads (here, with the tier off, views of the
+    partition blobs), each equal to its file."""
     files = {f"d/f{i:03d}.bin": bytes([i]) * 64 for i in range(32)}
     cluster = make_cluster(2, files)
     paths = sorted(files)
     sampler = GlobalUniformSampler(len(paths), 8, seed=0)
-    loader = PrefetchLoader(
-        sampler,
-        fetch_many=lambda idxs: cluster.read_many(
-            0, [paths[i] for i in idxs]),
-        decode=lambda bl: bl)
+    asked = []
+
+    def fetch_many(idxs):
+        asked.extend(paths[i] for i in idxs)
+        return cluster.read_many(0, [paths[i] for i in idxs])
+
+    loader = PrefetchLoader(sampler, fetch_many=fetch_many,
+                            decode=lambda bl: bl)
     seen = []
     for batch in loader.batches(4):
         assert len(batch) == 8
         seen.extend(batch)
-    assert all(isinstance(b, bytes) and len(b) == 64 for b in seen)
+    assert len(seen) == 32 and len(asked) >= len(seen)
+    for b, path in zip(seen, asked):
+        assert memoryview(b).readonly and len(b) == 64
+        assert bytes(b) == files[path]
+    assert any(isinstance(b, memoryview) for b in seen)
     with pytest.raises(ValueError):
         PrefetchLoader(sampler, decode=lambda b: b)  # no fetch at all
+
+
+def test_prefetch_loader_decode_packs_views_into_a_reused_buffer():
+    """A decode that packs each batch into a reused host buffer by slice
+    assignment, as a consumer staging batches for a device does, takes
+    the views ``read_many`` hands out as it took ``bytes``: the packed
+    batch equals its files, byte for byte."""
+    rng = random.Random(7)
+    files = {f"d/f{i:03d}.bin": rng.randbytes(40 + 37 * i)
+             for i in range(32)}
+    cluster = make_cluster(2, files)
+    paths = sorted(files)
+    sampler = GlobalUniformSampler(len(paths), 8, seed=3)
+    asked = []
+    ring = [np.zeros(sum(map(len, files.values())), np.uint8)
+            for _ in range(2)]
+    kinds = []
+
+    def fetch_many(idxs):
+        asked.append([paths[i] for i in idxs])
+        return cluster.read_many(1, asked[-1])
+
+    def decode(blobs):
+        kinds.extend(type(b) for b in blobs)
+        buf = ring[len(kinds) // 8 % len(ring)]
+        view, off = memoryview(buf), 0
+        for b in blobs:
+            view[off:off + len(b)] = b
+            off += len(b)
+        return buf[:off].tobytes()     # the buffer is packed again later
+
+    loader = PrefetchLoader(sampler, fetch_many=fetch_many, decode=decode,
+                            depth=2)
+    packed = list(loader.batches(8))
+    assert len(packed) == 8 and len(asked) >= 8
+    for got, batch in zip(packed, asked):
+        assert got == b"".join(files[p] for p in batch)
+    assert memoryview in kinds
 
 
 # ---- FS layer commit regression --------------------------------------------

@@ -37,9 +37,9 @@ BACKENDS = ["modeled", "socket", "shm", "rdma"]
 VARIANTS = ["r1", "r2", "lzss", "tier", "outputs", "open", "unbatched",
             "nomat", "serve", "kill"]
 CASES = [f"{b}-{v}" for b in BACKENDS for v in VARIANTS]
-# counters that time something (they differ run to run), and the one the
+# counters that time something (they differ run to run), and the two the
 # gather adds
-UNPINNED = ("files_gathered",)
+UNPINNED = ("files_gathered", "files_viewed")
 
 
 def make_files(n=48):

@@ -382,20 +382,21 @@ def test_schedule_spread_beats_node0_pin():
     def run(requesters, workers_per_node):
         spec = ClusterSpec(num_nodes=4, workers_per_node=workers_per_node,
                            cache_bytes=4 << 20, cache_policy="belady")
-        cluster = FanStoreCluster.from_spec(spec)
-        cluster.load_partitions(blobs)
-        sched = EpochSchedule.from_sampler(
-            sampler, paths, num_requesters=requesters,
-            workers_per_node=workers_per_node, cluster=cluster)
-        group = SchedulerGroup.for_schedule(cluster, sched, window_steps=2)
-        group.run_all()
-        group.close()
-        for r in sched.requesters:
-            node = r[0] if isinstance(r, tuple) else r
-            w = r[1] if isinstance(r, tuple) else 0
-            for s in sched.for_requester(r):
-                cluster.read_many(node, [s.path], worker_id=w)
-        return cluster.makespan_s()
+        with FanStoreCluster.from_spec(spec) as cluster:
+            cluster.load_partitions(blobs)
+            sched = EpochSchedule.from_sampler(
+                sampler, paths, num_requesters=requesters,
+                workers_per_node=workers_per_node, cluster=cluster)
+            group = SchedulerGroup.for_schedule(cluster, sched,
+                                                window_steps=2)
+            group.run_all()
+            group.close()
+            for r in sched.requesters:
+                node = r[0] if isinstance(r, tuple) else r
+                w = r[1] if isinstance(r, tuple) else 0
+                for s in sched.for_requester(r):
+                    cluster.read_many(node, [s.path], worker_id=w)
+            return cluster.makespan_s()
 
     pinned = run(1, 1)           # whole epoch through node 0
     spread = run(8, 2)           # one loader per (node, worker)
